@@ -50,6 +50,9 @@ def main() -> None:
         from benchmarks import common
         common.SMOKE = True
         args.full = False
+    # first jax import: after the device-count flag above
+    from repro.launch.compile_cache import configure_compile_cache
+    configure_compile_cache()
     print("name,us_per_call,derived")
     ok = True
     for name in mods:

@@ -16,7 +16,7 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 import jax
 import jax.numpy as jnp
 import numpy as np
-from repro.dist import shard_map  # version-compat wrapper
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs import registry

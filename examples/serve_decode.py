@@ -2,7 +2,11 @@
 (4/6), quantize-once packed weights, paged KV pool, interleaved chunked
 prefill + batched decode.
 
-    PYTHONPATH=src python examples/serve_decode.py [--arch yi_9b] [--tokens 32]
+    PYTHONPATH=src python examples/serve_decode.py [--arch llama_200m] \
+        [--reduced] [--tokens 32]
+
+Serves the registered configuration at its published width; `--reduced`
+swaps in the small same-family variant (what a CPU run wants).
 
 `--legacy` runs the old fixed-batch greedy loop instead (the baseline the
 benchmark compares against). `--data-shards N` serves through the
@@ -42,6 +46,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import registry
+from repro.launch.compile_cache import configure_compile_cache
 from repro.models import lm
 from repro.serve.decode import greedy_generate
 from repro.serve.engine import EngineConfig, Request, ServeEngine
@@ -50,7 +55,9 @@ from repro.serve.sampling import SamplingParams
 
 def main():
     ap = argparse.ArgumentParser()
-    ap.add_argument("--arch", default="yi_9b")
+    ap.add_argument("--arch", default="llama_200m")
+    ap.add_argument("--reduced", action="store_true",
+                    help="small same-family variant of the architecture")
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--prompt-len", type=int, default=32)
     ap.add_argument("--tokens", type=int, default=32)
@@ -110,8 +117,11 @@ def main():
                          "(implies --metrics)")
     args = ap.parse_args()
 
+    configure_compile_cache()
     backend = jax.default_backend().upper()
-    cfg = registry.get(args.arch).reduced()
+    cfg = registry.get(args.arch)
+    if args.reduced:
+        cfg = cfg.reduced()
     params = lm.init(cfg, jax.random.PRNGKey(0))
     b, s = args.batch, args.prompt_len
     rng = np.random.RandomState(1)

@@ -1,6 +1,10 @@
 """Serve over HTTP: the asyncio streaming frontend end-to-end.
 
-    PYTHONPATH=src python examples/serve_http.py [--arch yi_9b] [--tokens 24]
+    PYTHONPATH=src python examples/serve_http.py [--arch llama_200m] \
+        [--reduced] [--tokens 24]
+
+Serves the registered configuration at its published width; `--reduced`
+swaps in the small same-family variant (what a CPU run wants).
 
 Spawns the engine on its bridge thread behind `CompletionFrontend`
 (serve/frontend.py), then from stdlib-asyncio clients on localhost:
@@ -25,6 +29,7 @@ import jax
 import numpy as np
 
 from repro.configs import registry
+from repro.launch.compile_cache import configure_compile_cache
 from repro.models import lm
 from repro.serve.engine import EngineConfig, ServeEngine
 from repro.serve.frontend import CompletionFrontend, EngineBridge, \
@@ -111,14 +116,19 @@ async def scenario(port, prompts, max_new):
 
 def main():
     ap = argparse.ArgumentParser()
-    ap.add_argument("--arch", default="yi_9b")
+    ap.add_argument("--arch", default="llama_200m")
+    ap.add_argument("--reduced", action="store_true",
+                    help="small same-family variant of the architecture")
     ap.add_argument("--clients", type=int, default=3)
     ap.add_argument("--prompt-len", type=int, default=24)
     ap.add_argument("--tokens", type=int, default=24)
     ap.add_argument("--scheme", default="quartet2")
     args = ap.parse_args()
 
-    cfg = registry.get(args.arch).reduced()
+    configure_compile_cache()
+    cfg = registry.get(args.arch)
+    if args.reduced:
+        cfg = cfg.reduced()
     params = lm.init(cfg, jax.random.PRNGKey(0))
     rng = np.random.RandomState(1)
     prompts = [list(map(int, rng.randint(0, cfg.vocab, args.prompt_len)))

@@ -1,37 +1,10 @@
-"""Distribution layer: sharding rules, pipeline parallelism, NVFP4 gradient
-compression, and a version-spanning `shard_map` shim.
+"""Distribution layer: sharding rules, pipeline parallelism and NVFP4
+gradient compression.
 
-`shard_map` moved from `jax.experimental.shard_map` (kwarg `check_rep`) to
-`jax.shard_map` (kwarg `check_vma`) across jax releases; callers here use one
-spelling and run on either. Repo-wide distribution conventions (this shim,
-the OOB-high scatter-sentinel rule) are recorded in docs/CONVENTIONS.md.
+Programs here call `jax.shard_map` directly. The mesh-sharded serving step
+(`serve/decode.py::shard_serve_step`) is manual over "data" only
+(`axis_names={"data"}`), leaving every other mesh axis under GSPMD; the
+GPipe and gradient-compression programs are manual over all axes.
+Repo-wide distribution conventions (the OOB-high scatter-sentinel rule,
+the row-local decode contract) are recorded in docs/CONVENTIONS.md.
 """
-
-from __future__ import annotations
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma=None, check_rep=None,
-              auto=None):
-    """`jax.shard_map` / `jax.experimental.shard_map` compat wrapper.
-
-    `check_vma` (new spelling) and `check_rep` (old spelling) are the same
-    knob; pass either and it is translated to whatever the installed jax
-    expects.
-
-    `auto` names mesh axes left under GSPMD control while the rest go
-    manual — the sharded serving step uses it to keep packed weights
-    "model"-partitioned (XLA inserts the reductions) inside a manual
-    "data"-split over decode slots. Requires a jax whose shard_map takes
-    `auto`; passing a non-empty set on one that doesn't raises TypeError
-    rather than silently computing with replicated weights.
-    """
-    flag = check_vma if check_vma is not None else check_rep
-    try:
-        from jax import shard_map as _sm  # jax >= 0.6
-        kw = {} if flag is None else {"check_vma": flag}
-    except ImportError:
-        from jax.experimental.shard_map import shard_map as _sm
-        kw = {} if flag is None else {"check_rep": flag}
-    if auto:
-        kw["auto"] = frozenset(auto)
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)

@@ -11,9 +11,9 @@ Per-device seeds derive from (caller seed, axis_index, leaf index): devices
 must NOT share rounding randomness or the SR errors correlate and stop
 averaging out across the reduce.
 
-Callers enter through `repro.dist.shard_map` (the version shim, manual
-axes only — docs/CONVENTIONS.md §1); `tests/test_substrate.py` checks the
-compressed mean's accuracy and unbiasedness on a simulated 4-device mesh.
+Callers enter through `jax.shard_map`, manual over the DP axis;
+`tests/test_substrate.py` checks the compressed mean's accuracy and
+unbiasedness on a simulated 4-device mesh.
 """
 
 from __future__ import annotations

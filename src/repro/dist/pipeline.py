@@ -11,10 +11,7 @@ e4m3 group scales), the same format the gradient compression uses. Boundary
 compression is deterministic RTN — serving-style forward-only traffic, no
 unbiasedness requirement.
 
-Runs under the PLAIN manual `repro.dist.shard_map` shim (no `auto` axes),
-so the schedule's internal scans are safe — the while-body sharding
-limitation that forces the serving path to unroll does not apply here; see
-docs/CONVENTIONS.md §1.
+Runs under a `jax.shard_map` that is manual over every mesh axis.
 """
 
 from __future__ import annotations

@@ -28,9 +28,9 @@ slot-affine KV pool (serve/kv_pool.py) makes every cache access shard-local:
     each manual shard for no bandwidth win at decode batch sizes);
   - `PackedQWeight` leaves (quantize-once NVFP4 weights, core/linear.py)
     shard their out-feature axis — `packed`/`scales8` axis -2 — over
-    "model"; the per-matrix `gscale` replicates. "model" stays a GSPMD
-    `auto` axis inside the serving shard_map, so XLA inserts the activation
-    reductions for the row-split GEMMs;
+    "model"; the per-matrix `gscale` replicates. "model" stays under GSPMD
+    inside the serving shard_map (it is not in its `axis_names`), so XLA
+    inserts the activation reductions for the row-split GEMMs;
   - raw serving leaves (embeddings, norms, MLA's wkv_b, the head) fall back
     to `param_spec` with fsdp off — "data" never appears on weights (every
     shard needs the full model to decode its own slots).

@@ -34,6 +34,25 @@ def _resolve_interpret(interpret: bool | None) -> bool:
     return interpret
 
 
+def _unpartitioned(call, *args):
+    """Run a Pallas call where GSPMD cannot partition it.
+
+    Mosaic kernels cannot be partitioned automatically. The mesh-sharded
+    serving step is manual over "data" only and leaves "model" to GSPMD,
+    so there the call is made manual over the remaining non-trivial axes
+    with every operand replicated: each device of a "model" group runs the
+    whole call on its own shard of slots. Off-mesh (or when no such axis
+    is larger than 1) the call is made as it is."""
+    mesh = jax.sharding.get_abstract_mesh()
+    auto = set() if mesh.empty else {
+        a for a in mesh.auto_axes if mesh.shape[a] > 1}
+    if not auto:
+        return call(*args)
+    P = jax.sharding.PartitionSpec
+    return jax.shard_map(call, mesh=mesh, in_specs=P(), out_specs=P(),
+                         axis_names=auto, check_vma=False)(*args)
+
+
 @functools.partial(jax.jit, static_argnames=("window", "interpret"))
 def paged_attention(q, k_pool, v_pool, table, pos, *, window: int | None = None,
                     interpret: bool | None = None):
@@ -46,9 +65,10 @@ def paged_attention(q, k_pool, v_pool, table, pos, *, window: int | None = None,
     gather_view(v_pool, table), pos, window=window)` without ever
     materializing the gathered views. Returns (B, Sq, H, vd) in q.dtype.
     """
-    out = PA.paged_gqa_call(q, k_pool, v_pool, table,
-                            jnp.asarray(pos, jnp.int32), window=window,
-                            interpret=_resolve_interpret(interpret))
+    out = _unpartitioned(
+        functools.partial(PA.paged_gqa_call, window=window,
+                          interpret=_resolve_interpret(interpret)),
+        q, k_pool, v_pool, table, jnp.asarray(pos, jnp.int32))
     return out.astype(q.dtype)
 
 
@@ -66,9 +86,10 @@ def paged_mla_attention(q_abs, q_rope, cc_pool, kc_pool, table, pos, *,
     # the f32 image of mla_decode's 1/sqrt(nope+rope), so kernel and
     # reference multiply by the identical scalar
     scale = float(np.float32(1.0) / np.sqrt(np.float32(qk_dim)))
-    return PA.paged_mla_call(q_abs, q_rope, cc_pool, kc_pool, table,
-                             jnp.asarray(pos, jnp.int32), scale=scale,
-                             interpret=_resolve_interpret(interpret))
+    return _unpartitioned(
+        functools.partial(PA.paged_mla_call, scale=scale,
+                          interpret=_resolve_interpret(interpret)),
+        q_abs, q_rope, cc_pool, kc_pool, table, jnp.asarray(pos, jnp.int32))
 
 
 @functools.partial(jax.jit, static_argnames=("window", "interpret"))
@@ -86,9 +107,11 @@ def paged_attention_q(q, k_codes, k_scales, v_codes, v_scales, table, pos, *,
     the dequant is exact in f32/bf16, so parity with the gather-then-
     decode reference is the same contract as the bf16 kernel's.
     """
-    out = PA.paged_gqa_q_call(q, k_codes, k_scales, v_codes, v_scales, table,
-                              jnp.asarray(pos, jnp.int32), window=window,
-                              interpret=_resolve_interpret(interpret))
+    out = _unpartitioned(
+        functools.partial(PA.paged_gqa_q_call, window=window,
+                          interpret=_resolve_interpret(interpret)),
+        q, k_codes, k_scales, v_codes, v_scales, table,
+        jnp.asarray(pos, jnp.int32))
     return out.astype(q.dtype)
 
 
@@ -100,10 +123,11 @@ def paged_mla_attention_q(q_abs, q_rope, cc_codes, cc_scales, kc_codes,
     (packed-operand twin of `paged_mla_attention`; operands are the
     unbundled PackedKV leaves of the cc / kc pools)."""
     scale = float(np.float32(1.0) / np.sqrt(np.float32(qk_dim)))
-    return PA.paged_mla_q_call(q_abs, q_rope, cc_codes, cc_scales, kc_codes,
-                               kc_scales, table,
-                               jnp.asarray(pos, jnp.int32), scale=scale,
-                               interpret=_resolve_interpret(interpret))
+    return _unpartitioned(
+        functools.partial(PA.paged_mla_q_call, scale=scale,
+                          interpret=_resolve_interpret(interpret)),
+        q_abs, q_rope, cc_codes, cc_scales, kc_codes, kc_scales, table,
+        jnp.asarray(pos, jnp.int32))
 
 
 def quartet2_backward_gemm(a, b, rht_key, sr_key_a, sr_key_b, *,

@@ -43,11 +43,13 @@ gathered-zeros result.
 Each variant also has a `_q` twin consuming the NVFP4-quantized pool
 (`serve.kv_pool.PackedKV`): the packed-operand BlockSpecs DMA uint8 e2m1
 code pairs (d/2 bytes) plus uint8 e4m3 scale bits (d/16 bytes) per block —
-0.28125x the bf16 HBM bytes — and `_dequant_tile` decodes them block-wise
-in VMEM (arithmetic e2m1/e4m3 decode, no gathers) before the SAME online
-softmax sweep. Dequant is exact in f32, so `_q` kernel outputs match the
-gather-then-decode reference bit-for-bit at the operand level; the shared
-sweep keeps the flash numerics identical across storage modes.
+0.28125x the bf16 HBM bytes — and `_dequant_halves` decodes them
+block-wise in VMEM (arithmetic e2m1/e4m3 decode, no gathers) into even and
+odd feature halves, which meet the matching halves of q and of the output
+(split and re-interleaved by XLA outside the kernel) in the SAME online
+softmax sweep. Dequant is exact in f32, so `_q` kernel operands match the
+gather-then-decode reference bit-for-bit; only the order in which the two
+halves' dot products are summed differs.
 """
 
 from __future__ import annotations
@@ -92,17 +94,45 @@ def _decode_e4m3_bits(bits):
                      * jnp.exp2((e - 7).astype(jnp.float32)))
 
 
-def _dequant_tile(codes_ref, scales_ref):
-    """Dequantize one packed pool block in VMEM: (1, BS, ..., d/2) uint8
-    code pairs + (1, BS, ..., d/16) e4m3 scale bits -> (BS, ..., d) f32.
-    Exact: every e2m1 x e4m3 product is f32 (and bf16) representable, so
-    this sees bit-identical operands to the gather-path bf16 dequant."""
-    packed = codes_ref[0]
-    lo = (packed & 0xF).astype(jnp.uint8)
-    hi = ((packed >> 4) & 0xF).astype(jnp.uint8)
-    codes = jnp.stack([lo, hi], axis=-1).reshape(*packed.shape[:-1], -1)
-    scales = _decode_e4m3_bits(scales_ref[0])
-    return _decode_e2m1(codes) * jnp.repeat(scales, F.GROUP, axis=-1)
+def _dequant_halves(codes, scale_bits):
+    """Dequantize packed pool operands in VMEM without an interleave:
+    uint8 e2m1 code pairs (..., R, d/2) + e4m3 scale bits (..., R, d/16)
+    -> the EVEN and ODD feature halves (..., R, d/2) f32 (byte i holds
+    features 2i in its low and 2i+1 in its high nibble). Callers contract
+    each half against the matching half of q or of the output, so no
+    lane-merging reshape reaches Mosaic (which refuses that shape cast).
+
+    Both features of byte i fall in scale group i // 8; the scales are
+    broadcast along the lanes by a 0/1 matmul (batched over a leading axis
+    for 3-D operands). That is exact: e4m3 values are bf16-representable
+    and each output sums one nonzero term. Every e2m1 x e4m3 product is
+    f32 (and bf16) representable, so the halves hold bit-identical
+    operands to the gather-path bf16 dequant."""
+    c = codes.astype(jnp.int32)
+    sc = _decode_e4m3_bits(scale_bits)
+    g, half = sc.shape[-1], c.shape[-1]
+    expand = (jax.lax.broadcasted_iota(jnp.int32, (g, half), 1)
+              // (F.GROUP // 2)
+              == jax.lax.broadcasted_iota(jnp.int32, (g, half), 0)
+              ).astype(jnp.float32)
+    if sc.ndim == 3:
+        sc = jax.lax.dot_general(
+            sc, jnp.broadcast_to(expand, (sc.shape[0], g, half)),
+            (((2,), (1,)), ((0,), (0,))), preferred_element_type=jnp.float32)
+    else:
+        sc = jnp.dot(sc, expand, preferred_element_type=jnp.float32)
+    return _decode_e2m1(c & 0xF) * sc, _decode_e2m1(c >> 4) * sc
+
+
+def _split_halves(x):
+    """(..., d) -> [even features, odd features], the q-side match of
+    `_dequant_halves` (done by XLA outside the kernel)."""
+    return [x[..., 0::2], x[..., 1::2]]
+
+
+def _interleave_halves(even, odd):
+    """Inverse of `_split_halves` (done by XLA outside the kernel)."""
+    return jnp.stack([even, odd], axis=-1).reshape(*even.shape[:-1], -1)
 
 
 def _positions(p0, sq: int, bs: int, j):
@@ -115,28 +145,32 @@ def _positions(p0, sq: int, bs: int, j):
 def _online_update(s, ok, m_ref, l_ref, acc_ref, vals):
     """One flash step: fold masked scores `s` (..., Sq-ish, BS) and the block
     values into the running (m, l, acc) scratch. `vals` maps probabilities
-    (..., BS) -> the block's value contribution, so the two variants share
-    the numerics (exp of masked lanes is forced to exactly 0, and a block
-    that changes nothing multiplies the accumulators by exactly 1.0)."""
+    (..., BS) -> the block's value contribution per feature part (acc_ref's
+    leading axis), so the variants share the numerics (exp of masked lanes
+    is forced to exactly 0, and a block that changes nothing multiplies the
+    accumulators by exactly 1.0)."""
     s = jnp.where(ok, s, NEG_INF)
     m_prev = m_ref[...]
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
     p = jnp.where(ok, jnp.exp(s - m_new[..., None]), 0.0)
     corr = jnp.exp(m_prev - m_new)
     l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=-1)
-    acc_ref[...] = acc_ref[...] * corr[..., None] + vals(p)
+    for i, v in enumerate(vals(p)):
+        acc_ref[i] = acc_ref[i] * corr[..., None] + v
     m_ref[...] = m_new
 
 
-def _gqa_sweep(table_ref, pos_ref, q_ref, o_ref, m_ref, l_ref, acc_ref,
-               load_kv, *, kv: int, vd: int, bs: int, sentinel: int,
+def _gqa_sweep(table_ref, pos_ref, q_refs, o_refs, m_ref, l_ref, acc_ref,
+               load_kv, *, kv: int, bs: int, sentinel: int,
                window: int | None, sqrt_hd: float):
-    """Shared GQA flash sweep; `load_kv()` yields the cell's f32 (BS, KV,
-    hd) / (BS, KV, vd) operands — a bf16 cast for the reference pool, a
-    VMEM dequant for the packed one — so both storage modes run literally
-    the same softmax/value arithmetic."""
+    """Shared GQA flash sweep over feature parts: one part (the whole head
+    dim) for the bf16 pool, two (even/odd halves) for the packed one.
+    `load_kv()` yields the cell's f32 keys (KV, hd_i, BS) and values
+    (KV, BS, vd_i) per part — a bf16 cast or a VMEM dequant — and scores
+    sum the parts' dots, so both storage modes run the same softmax/value
+    arithmetic."""
     b, j = pl.program_id(0), pl.program_id(1)
-    sq, h, hd = q_ref.shape[1], q_ref.shape[2], q_ref.shape[3]
+    sq, h = q_refs[0].shape[1], q_refs[0].shape[2]
     rep = h // kv
 
     @pl.when(j == 0)
@@ -156,14 +190,17 @@ def _gqa_sweep(table_ref, pos_ref, q_ref, o_ref, m_ref, l_ref, acc_ref,
 
     @pl.when(live)
     def _block():
-        q = q_ref[0].astype(jnp.float32)    # (Sq, H, hd)
-        k, v = load_kv()                    # (BS, KV, hd), (BS, KV, vd) f32
-        # grouped scores: (KV, Sq*rep, hd) x (KV, hd, BS) -> (KV, Sq*rep, BS)
-        qg = q.reshape(sq, kv, rep, hd).transpose(1, 0, 2, 3)
-        qg = qg.reshape(kv, sq * rep, hd)
-        s = jax.lax.dot_general(
-            qg, k.transpose(1, 2, 0), (((2,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32) / sqrt_hd
+        ks, vs = load_kv()
+        # grouped scores: (KV, Sq*rep, hd_i) x (KV, hd_i, BS) -> (KV, Sq*rep, BS)
+        parts = []
+        for q_ref, k in zip(q_refs, ks):
+            q = q_ref[0].astype(jnp.float32)    # (Sq, H, hd_i)
+            qg = q.reshape(sq, kv, rep, -1).transpose(1, 0, 2, 3)
+            qg = qg.reshape(kv, sq * rep, -1)
+            parts.append(jax.lax.dot_general(
+                qg, k, (((2,), (1,)), ((0,), (0,))),
+                preferred_element_type=jnp.float32))
+        s = functools.reduce(jnp.add, parts) / sqrt_hd
         s = s.reshape(kv, sq, rep, bs)
         kj, qpos = _positions(p0, sq, bs, j)
         ok = kj <= qpos
@@ -171,50 +208,59 @@ def _gqa_sweep(table_ref, pos_ref, q_ref, o_ref, m_ref, l_ref, acc_ref,
             ok &= kj > qpos - window
         ok = ok[None, :, None, :]           # (1, Sq, 1, BS)
 
-        def vals(p):                        # (KV, Sq, rep, BS) -> value sum
-            pv = jax.lax.dot_general(
-                p.reshape(kv, sq * rep, bs), v.transpose(1, 0, 2),
-                (((2,), (1,)), ((0,), (0,))),
-                preferred_element_type=jnp.float32)
-            return pv.reshape(kv, sq, rep, vd)
+        def vals(p):                        # (KV, Sq, rep, BS) -> value sums
+            pf = p.reshape(kv, sq * rep, bs)
+            return [jax.lax.dot_general(
+                pf, v, (((2,), (1,)), ((0,), (0,))),
+                preferred_element_type=jnp.float32
+            ).reshape(kv, sq, rep, v.shape[-1]) for v in vs]
 
         _online_update(s, ok, m_ref, l_ref, acc_ref, vals)
 
     @pl.when(j == pl.num_programs(1) - 1)
     def _final():
-        o = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)[..., None]
-        o_ref[0] = o.transpose(1, 0, 2, 3).reshape(sq, h, vd)
+        l = jnp.maximum(l_ref[...], 1e-30)[..., None]
+        for i, o_ref in enumerate(o_refs):
+            o = acc_ref[i] / l
+            o_ref[0] = o.transpose(1, 0, 2, 3).reshape(sq, h, -1)
 
 
 def _gqa_kernel(table_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
                 m_ref, l_ref, acc_ref, *, bs: int, sentinel: int,
                 window: int | None, sqrt_hd: float):
-    _gqa_sweep(table_ref, pos_ref, q_ref, o_ref, m_ref, l_ref, acc_ref,
-               lambda: (k_ref[0].astype(jnp.float32),
-                        v_ref[0].astype(jnp.float32)),
-               kv=k_ref.shape[2], vd=v_ref.shape[3], bs=bs,
-               sentinel=sentinel, window=window, sqrt_hd=sqrt_hd)
+    _gqa_sweep(table_ref, pos_ref, (q_ref,), (o_ref,), m_ref, l_ref, acc_ref,
+               lambda: ([k_ref[0].astype(jnp.float32).transpose(1, 2, 0)],
+                        [v_ref[0].astype(jnp.float32).transpose(1, 0, 2)]),
+               kv=k_ref.shape[2], bs=bs, sentinel=sentinel, window=window,
+               sqrt_hd=sqrt_hd)
 
 
-def _gqa_q_kernel(table_ref, pos_ref, q_ref, kc_ref, ks_ref, vc_ref, vs_ref,
-                  o_ref, m_ref, l_ref, acc_ref, *, bs: int, sentinel: int,
-                  window: int | None, sqrt_hd: float):
+def _gqa_q_kernel(table_ref, pos_ref, qe_ref, qo_ref, kc_ref, ks_ref, vc_ref,
+                  vs_ref, oe_ref, oo_ref, m_ref, l_ref, acc_ref, *, bs: int,
+                  sentinel: int, window: int | None, sqrt_hd: float):
     """Packed-operand twin: K/V arrive as e2m1 code pairs + e4m3 scale bits
-    and dequantize in VMEM only for live cells."""
-    _gqa_sweep(table_ref, pos_ref, q_ref, o_ref, m_ref, l_ref, acc_ref,
-               lambda: (_dequant_tile(kc_ref, ks_ref),
-                        _dequant_tile(vc_ref, vs_ref)),
-               kv=kc_ref.shape[2], vd=vc_ref.shape[3] * 2, bs=bs,
+    and dequantize in VMEM only for live cells, as even/odd halves that
+    meet q's halves (and fill the output's) split outside the kernel."""
+    def load_kv():
+        def halves(c_ref, s_ref):           # (BS, KV, .) -> (KV, BS, .)
+            return _dequant_halves(c_ref[0].astype(jnp.int32).transpose(1, 0, 2),
+                                   s_ref[0].astype(jnp.int32).transpose(1, 0, 2))
+        ks = [k.transpose(0, 2, 1) for k in halves(kc_ref, ks_ref)]
+        return ks, list(halves(vc_ref, vs_ref))
+
+    _gqa_sweep(table_ref, pos_ref, (qe_ref, qo_ref), (oe_ref, oo_ref),
+               m_ref, l_ref, acc_ref, load_kv, kv=kc_ref.shape[2], bs=bs,
                sentinel=sentinel, window=window, sqrt_hd=sqrt_hd)
 
 
-def _mla_sweep(table_ref, pos_ref, qa_ref, qr_ref, o_ref,
+def _mla_sweep(table_ref, pos_ref, qa_refs, qr_refs, o_refs,
                m_ref, l_ref, acc_ref, load_cc_kc, *, bs: int, sentinel: int,
                scale: float):
-    """Shared MLA flash sweep; `load_cc_kc()` yields the cell's f32
-    (BS, lora) / (BS, rope) latent operands (bf16 cast or VMEM dequant)."""
+    """Shared MLA flash sweep over feature parts (see `_gqa_sweep`);
+    `load_cc_kc()` yields the cell's f32 latent operands per part,
+    (BS, lora_i) and (BS, rope_i)."""
     b, j = pl.program_id(0), pl.program_id(1)
-    sq, h, lora = qa_ref.shape[1], qa_ref.shape[2], qa_ref.shape[3]
+    sq, h = qa_refs[0].shape[1], qa_refs[0].shape[2]
 
     @pl.when(j == 0)
     def _init():
@@ -227,45 +273,49 @@ def _mla_sweep(table_ref, pos_ref, qa_ref, qr_ref, o_ref,
 
     @pl.when(live)
     def _block():
-        qa = qa_ref[0].astype(jnp.float32).reshape(sq * h, lora)
-        qr = qr_ref[0].astype(jnp.float32).reshape(sq * h, -1)
-        cc, kc = load_cc_kc()               # (BS, lora), (BS, rope) f32
-        s = (jnp.dot(qa, cc.T, preferred_element_type=jnp.float32)
-             + jnp.dot(qr, kc.T, preferred_element_type=jnp.float32)) * scale
+        ccs, kcs = load_cc_kc()
+        parts = [jnp.dot(q_ref[0].astype(jnp.float32).reshape(sq * h, -1),
+                         c.T, preferred_element_type=jnp.float32)
+                 for q_ref, c in zip((*qa_refs, *qr_refs), (*ccs, *kcs))]
+        s = functools.reduce(jnp.add, parts) * scale
         s = s.reshape(sq, h, bs)
         kj, qpos = _positions(p0, sq, bs, j)
         ok = (kj <= qpos)[:, None, :]       # (Sq, 1, BS)
 
         def vals(p):                        # (Sq, H, BS) -> latent readout
-            return jnp.dot(p.reshape(sq * h, bs), cc,
-                           preferred_element_type=jnp.float32
-                           ).reshape(sq, h, lora)
+            pf = p.reshape(sq * h, bs)
+            return [jnp.dot(pf, c, preferred_element_type=jnp.float32
+                            ).reshape(sq, h, c.shape[-1]) for c in ccs]
 
         _online_update(s, ok, m_ref, l_ref, acc_ref, vals)
 
     @pl.when(j == pl.num_programs(1) - 1)
     def _final():
-        o_ref[0] = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)[..., None]
+        l = jnp.maximum(l_ref[...], 1e-30)[..., None]
+        for i, o_ref in enumerate(o_refs):
+            o_ref[0] = acc_ref[i] / l
 
 
 def _mla_kernel(table_ref, pos_ref, qa_ref, qr_ref, cc_ref, kc_ref, o_ref,
                 m_ref, l_ref, acc_ref, *, bs: int, sentinel: int,
                 scale: float):
-    _mla_sweep(table_ref, pos_ref, qa_ref, qr_ref, o_ref,
+    _mla_sweep(table_ref, pos_ref, (qa_ref,), (qr_ref,), (o_ref,),
                m_ref, l_ref, acc_ref,
-               lambda: (cc_ref[0].astype(jnp.float32),
-                        kc_ref[0].astype(jnp.float32)),
+               lambda: ([cc_ref[0].astype(jnp.float32)],
+                        [kc_ref[0].astype(jnp.float32)]),
                bs=bs, sentinel=sentinel, scale=scale)
 
 
-def _mla_q_kernel(table_ref, pos_ref, qa_ref, qr_ref, ccc_ref, ccs_ref,
-                  kcc_ref, kcs_ref, o_ref, m_ref, l_ref, acc_ref, *,
-                  bs: int, sentinel: int, scale: float):
-    """Packed-operand twin: both latent pools arrive as NVFP4 bytes."""
-    _mla_sweep(table_ref, pos_ref, qa_ref, qr_ref, o_ref,
-               m_ref, l_ref, acc_ref,
-               lambda: (_dequant_tile(ccc_ref, ccs_ref),
-                        _dequant_tile(kcc_ref, kcs_ref)),
+def _mla_q_kernel(table_ref, pos_ref, qae_ref, qao_ref, qre_ref, qro_ref,
+                  ccc_ref, ccs_ref, kcc_ref, kcs_ref, oe_ref, oo_ref,
+                  m_ref, l_ref, acc_ref, *, bs: int, sentinel: int,
+                  scale: float):
+    """Packed-operand twin: both latent pools arrive as NVFP4 bytes and
+    dequantize to even/odd halves (see `_gqa_q_kernel`)."""
+    _mla_sweep(table_ref, pos_ref, (qae_ref, qao_ref), (qre_ref, qro_ref),
+               (oe_ref, oo_ref), m_ref, l_ref, acc_ref,
+               lambda: (list(_dequant_halves(ccc_ref[0], ccs_ref[0])),
+                        list(_dequant_halves(kcc_ref[0], kcs_ref[0]))),
                bs=bs, sentinel=sentinel, scale=scale)
 
 
@@ -280,75 +330,96 @@ def _table_spec_index(sentinel):
     return index
 
 
+def _row(*block):
+    """BlockSpec of one batch row's full (Sq, H, d) slab."""
+    return pl.BlockSpec((1, *block), lambda i, j, t, p: (i, 0, 0, 0))
+
+
+def _gqa_grid_spec(b, maxb, sq, h, kv, q_dims, pool_blocks, n_blocks,
+                   vdp, n_parts):
+    """(row, logical block) grid with the table-resolved pool BlockSpecs;
+    one q input and one output per feature part of width `q_dims[i]` /
+    `vdp`, and an (n_parts, KV, Sq, rep, vdp) accumulator."""
+    idx = _table_spec_index(n_blocks)
+    rep = h // kv
+    return pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(b, maxb),
+        in_specs=[_row(sq, h, d) for d in q_dims]
+                 + [pl.BlockSpec(blk, idx) for blk in pool_blocks],
+        out_specs=[_row(sq, h, vdp)] * n_parts,
+        scratch_shapes=[
+            pltpu.VMEM((kv, sq, rep), jnp.float32),
+            pltpu.VMEM((kv, sq, rep), jnp.float32),
+            pltpu.VMEM((n_parts, kv, sq, rep, vdp), jnp.float32),
+        ],
+    )
+
+
 def paged_gqa_call(q, k_pool, v_pool, table, pos, *, window: int | None,
                    interpret: bool):
     b, sq, h, hd = q.shape
     n_blocks, bs, kv = k_pool.shape[0], k_pool.shape[1], k_pool.shape[2]
     vd = v_pool.shape[3]
-    maxb = table.shape[1]
-    rep = h // kv
     sqrt_hd = float(np.sqrt(np.float32(hd)))  # matches decode_sdpa's divisor
-    idx = _table_spec_index(n_blocks)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(b, maxb),
-        in_specs=[
-            pl.BlockSpec((1, sq, h, hd), lambda i, j, t, p: (i, 0, 0, 0)),
-            pl.BlockSpec((1, bs, kv, hd), idx),
-            pl.BlockSpec((1, bs, kv, vd), idx),
-        ],
-        out_specs=pl.BlockSpec((1, sq, h, vd), lambda i, j, t, p: (i, 0, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((kv, sq, rep), jnp.float32),
-            pltpu.VMEM((kv, sq, rep), jnp.float32),
-            pltpu.VMEM((kv, sq, rep, vd), jnp.float32),
-        ],
-    )
-    return pl.pallas_call(
+    grid_spec = _gqa_grid_spec(b, table.shape[1], sq, h, kv, [hd],
+                               [(1, bs, kv, hd), (1, bs, kv, vd)], n_blocks,
+                               vd, 1)
+    (out,) = pl.pallas_call(
         functools.partial(_gqa_kernel, bs=bs, sentinel=n_blocks,
                           window=window, sqrt_hd=sqrt_hd),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, sq, h, vd), jnp.float32),
+        out_shape=[jax.ShapeDtypeStruct((b, sq, h, vd), jnp.float32)],
         interpret=interpret,
     )(table, pos, q, k_pool, v_pool)
+    return out
 
 
 def paged_gqa_q_call(q, k_codes, k_scales, v_codes, v_scales, table, pos, *,
                      window: int | None, interpret: bool):
     """GQA flash-decode over the NVFP4-packed pool: same grid, same index
     maps, but each pool operand is a (codes, scale-bits) uint8 pair whose
-    BlockSpecs move 0.28125x the bf16 bytes per cell."""
+    BlockSpecs move 0.28125x the bf16 bytes per cell. q enters, and the
+    output leaves, as even/odd feature halves (see `_dequant_halves`)."""
     b, sq, h, hd = q.shape
     n_blocks, bs, kv = k_codes.shape[0], k_codes.shape[1], k_codes.shape[2]
-    vd = v_codes.shape[3] * 2
-    maxb = table.shape[1]
-    rep = h // kv
+    vdh = v_codes.shape[3]
     sqrt_hd = float(np.sqrt(np.float32(hd)))  # matches decode_sdpa's divisor
-    idx = _table_spec_index(n_blocks)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(b, maxb),
-        in_specs=[
-            pl.BlockSpec((1, sq, h, hd), lambda i, j, t, p: (i, 0, 0, 0)),
-            pl.BlockSpec((1, bs, kv, hd // 2), idx),
-            pl.BlockSpec((1, bs, kv, hd // F.GROUP), idx),
-            pl.BlockSpec((1, bs, kv, vd // 2), idx),
-            pl.BlockSpec((1, bs, kv, vd // F.GROUP), idx),
-        ],
-        out_specs=pl.BlockSpec((1, sq, h, vd), lambda i, j, t, p: (i, 0, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((kv, sq, rep), jnp.float32),
-            pltpu.VMEM((kv, sq, rep), jnp.float32),
-            pltpu.VMEM((kv, sq, rep, vd), jnp.float32),
-        ],
-    )
-    return pl.pallas_call(
+    grid_spec = _gqa_grid_spec(
+        b, table.shape[1], sq, h, kv, [hd // 2] * 2,
+        [(1, bs, kv, hd // 2), (1, bs, kv, hd // F.GROUP),
+         (1, bs, kv, vdh), (1, bs, kv, 2 * vdh // F.GROUP)],
+        n_blocks, vdh, 2)
+    oe, oo = pl.pallas_call(
         functools.partial(_gqa_q_kernel, bs=bs, sentinel=n_blocks,
                           window=window, sqrt_hd=sqrt_hd),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, sq, h, vd), jnp.float32),
+        out_shape=[jax.ShapeDtypeStruct((b, sq, h, vdh), jnp.float32)] * 2,
         interpret=interpret,
-    )(table, pos, q, k_codes, k_scales, v_codes, v_scales)
+    )(table, pos, *_split_halves(q), k_codes, k_scales, v_codes, v_scales)
+    return _interleave_halves(oe, oo)
+
+
+def _mla_grid_spec(b, maxb, sq, h, q_dims, pool_blocks, n_blocks, lorap,
+                   n_parts):
+    """MLA twin of `_gqa_grid_spec` (3-D shared latent pools)."""
+    idx = _table_spec_index(n_blocks)
+
+    def pool_idx3(i, j, t, p):
+        return idx(i, j, t, p)[:3]
+
+    return pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(b, maxb),
+        in_specs=[_row(sq, h, d) for d in q_dims]
+                 + [pl.BlockSpec(blk, pool_idx3) for blk in pool_blocks],
+        out_specs=[_row(sq, h, lorap)] * n_parts,
+        scratch_shapes=[
+            pltpu.VMEM((sq, h), jnp.float32),
+            pltpu.VMEM((sq, h), jnp.float32),
+            pltpu.VMEM((n_parts, sq, h, lorap), jnp.float32),
+        ],
+    )
 
 
 def paged_mla_call(q_abs, q_rope, cc_pool, kc_pool, table, pos, *,
@@ -356,72 +427,37 @@ def paged_mla_call(q_abs, q_rope, cc_pool, kc_pool, table, pos, *,
     b, sq, h, lora = q_abs.shape
     rope = q_rope.shape[3]
     n_blocks, bs = cc_pool.shape[0], cc_pool.shape[1]
-    maxb = table.shape[1]
-    idx = _table_spec_index(n_blocks)
-
-    def pool_idx3(i, j, t, p):
-        return idx(i, j, t, p)[:3]
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(b, maxb),
-        in_specs=[
-            pl.BlockSpec((1, sq, h, lora), lambda i, j, t, p: (i, 0, 0, 0)),
-            pl.BlockSpec((1, sq, h, rope), lambda i, j, t, p: (i, 0, 0, 0)),
-            pl.BlockSpec((1, bs, lora), pool_idx3),
-            pl.BlockSpec((1, bs, rope), pool_idx3),
-        ],
-        out_specs=pl.BlockSpec((1, sq, h, lora),
-                               lambda i, j, t, p: (i, 0, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((sq, h), jnp.float32),
-            pltpu.VMEM((sq, h), jnp.float32),
-            pltpu.VMEM((sq, h, lora), jnp.float32),
-        ],
-    )
-    return pl.pallas_call(
+    grid_spec = _mla_grid_spec(b, table.shape[1], sq, h, [lora, rope],
+                               [(1, bs, lora), (1, bs, rope)], n_blocks,
+                               lora, 1)
+    (out,) = pl.pallas_call(
         functools.partial(_mla_kernel, bs=bs, sentinel=n_blocks, scale=scale),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, sq, h, lora), jnp.float32),
+        out_shape=[jax.ShapeDtypeStruct((b, sq, h, lora), jnp.float32)],
         interpret=interpret,
     )(table, pos, q_abs, q_rope, cc_pool, kc_pool)
+    return out
 
 
 def paged_mla_q_call(q_abs, q_rope, cc_codes, cc_scales, kc_codes, kc_scales,
                      table, pos, *, scale: float, interpret: bool):
-    """Absorbed-form MLA flash-decode over NVFP4-packed latent pools."""
+    """Absorbed-form MLA flash-decode over NVFP4-packed latent pools (q and
+    the output as even/odd feature halves, as in `paged_gqa_q_call`)."""
     b, sq, h, lora = q_abs.shape
     rope = q_rope.shape[3]
     n_blocks, bs = cc_codes.shape[0], cc_codes.shape[1]
-    maxb = table.shape[1]
-    idx = _table_spec_index(n_blocks)
-
-    def pool_idx3(i, j, t, p):
-        return idx(i, j, t, p)[:3]
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(b, maxb),
-        in_specs=[
-            pl.BlockSpec((1, sq, h, lora), lambda i, j, t, p: (i, 0, 0, 0)),
-            pl.BlockSpec((1, sq, h, rope), lambda i, j, t, p: (i, 0, 0, 0)),
-            pl.BlockSpec((1, bs, lora // 2), pool_idx3),
-            pl.BlockSpec((1, bs, lora // F.GROUP), pool_idx3),
-            pl.BlockSpec((1, bs, rope // 2), pool_idx3),
-            pl.BlockSpec((1, bs, rope // F.GROUP), pool_idx3),
-        ],
-        out_specs=pl.BlockSpec((1, sq, h, lora),
-                               lambda i, j, t, p: (i, 0, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((sq, h), jnp.float32),
-            pltpu.VMEM((sq, h), jnp.float32),
-            pltpu.VMEM((sq, h, lora), jnp.float32),
-        ],
-    )
-    return pl.pallas_call(
+    grid_spec = _mla_grid_spec(
+        b, table.shape[1], sq, h, [lora // 2] * 2 + [rope // 2] * 2,
+        [(1, bs, lora // 2), (1, bs, lora // F.GROUP),
+         (1, bs, rope // 2), (1, bs, rope // F.GROUP)],
+        n_blocks, lora // 2, 2)
+    oe, oo = pl.pallas_call(
         functools.partial(_mla_q_kernel, bs=bs, sentinel=n_blocks,
                           scale=scale),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, sq, h, lora), jnp.float32),
+        out_shape=[jax.ShapeDtypeStruct((b, sq, h, lora // 2),
+                                        jnp.float32)] * 2,
         interpret=interpret,
-    )(table, pos, q_abs, q_rope, cc_codes, cc_scales, kc_codes, kc_scales)
+    )(table, pos, *_split_halves(q_abs), *_split_halves(q_rope),
+      cc_codes, cc_scales, kc_codes, kc_scales)
+    return _interleave_halves(oe, oo)

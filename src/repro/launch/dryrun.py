@@ -2,9 +2,12 @@ import os
 # 512 host-platform placeholder devices for the production mesh; backend
 # optimization level 0 halves compile time with IDENTICAL cost-model output
 # (verified: flops/bytes/collectives match default opt bit-for-bit — the
-# SPMD partitioner runs either way and we never execute the code).
+# SPMD partitioner runs either way and we never execute the code). Pinned
+# to the CPU, so neither this process nor its `--all` children (which
+# inherit the environment) ever take an attached TPU.
 os.environ["XLA_FLAGS"] = ("--xla_force_host_platform_device_count=512 "
                            "--xla_backend_optimization_level=0")
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell on the
 production mesh, print memory_analysis / cost_analysis, and extract the
@@ -14,9 +17,9 @@ roofline terms (compute / memory / collective) from the compiled artifact.
     PYTHONPATH=src python -m repro.launch.dryrun --all            # every cell
     PYTHONPATH=src python -m repro.launch.dryrun --all --multi-pod
 
-The two XLA_FLAGS lines above MUST precede any jax import: this container has
-one CPU device and the 16x16(x2-pod) mesh needs 512 host-platform
-placeholders; jax locks the device count on first init. Smoke tests and
+The environment lines above MUST precede any jax import: the 16x16(x2-pod)
+mesh needs 512 host-platform placeholders, and jax locks the platform and
+the device count on first init. Smoke tests and
 benches never import this module, so they still see 1 device.
 """
 

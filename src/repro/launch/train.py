@@ -15,6 +15,7 @@ import jax
 
 from repro.configs import registry
 from repro.data.pipeline import DataConfig, SyntheticCorpus
+from repro.launch.compile_cache import configure_compile_cache
 from repro.models import lm
 from repro.train.train_step import make_train_step
 from repro.train.trainer import Trainer, TrainerConfig
@@ -37,6 +38,7 @@ def main():
     ap.add_argument("--resume", action="store_true")
     args = ap.parse_args()
 
+    configure_compile_cache()
     cfg = registry.get(args.arch)
     if args.reduced:
         cfg = cfg.reduced()

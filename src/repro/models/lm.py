@@ -371,14 +371,7 @@ REMAT = False
 def _run_stages(params, x, cfg, scheme, seed, *, mode, caches=None,
                 pos=None, positions=None, enc_out=None, stages=None,
                 layer_offset=0, active=None, block_table=None,
-                paged_kernel=False, unroll_stages=False):
-    """`unroll_stages=True` fully unrolls the layer scan (lax.scan unroll ==
-    trip count, so no while op reaches XLA). Only the mesh-sharded serving
-    step sets it, and only when the mesh has a non-trivial GSPMD `auto` axis:
-    this XLA's SPMD partitioner cannot propagate shardings into a while body
-    inside a manual-subgroup (shard_map auto) region — it CHECK-fails on
-    hlo_sharding_util's IsManualSubgroup. Costs HLO size O(depth), which
-    serving (compile once, decode forever) tolerates."""
+                paged_kernel=False):
     specs = stages if stages is not None else layer_specs(cfg)
     aux_total = jnp.zeros((), jnp.float32)
     new_caches = []
@@ -407,15 +400,12 @@ def _run_stages(params, x, cfg, scheme, seed, *, mode, caches=None,
         # remat on every differentiated path (train + the encoder stack that
         # feeds the decoder's training loss); decode/prefill have no backward
         fn = jax.checkpoint(body) if (REMAT and mode in ("train", "encode")) else body
-        unroll = count if unroll_stages else 1
         if cache_s is None:
             (x, aux_total), _ = jax.lax.scan(
-                fn, (x, aux_total),
-                (jnp.arange(count), sp, None), unroll=unroll)
+                fn, (x, aux_total), (jnp.arange(count), sp, None))
         else:
             (x, aux_total), new_cache_s = jax.lax.scan(
-                fn, (x, aux_total), (jnp.arange(count), sp, cache_s),
-                unroll=unroll)
+                fn, (x, aux_total), (jnp.arange(count), sp, cache_s))
             new_caches.append(new_cache_s)
         off += count * len(pattern)
     return x, (new_caches if caches is not None else None), aux_total
@@ -429,8 +419,7 @@ def head_weight(params, cfg):
 
 def forward(params, cfg: ArchConfig, inputs, scheme: str, seed: jax.Array,
             *, caches=None, mode: str = "train", pos=None, head: bool = True,
-            active=None, block_table=None, paged_kernel=False,
-            unroll_stages=False):
+            active=None, block_table=None, paged_kernel=False):
     """Full model. inputs: {"tokens": (B,S)} or {"embeds": (B,S,D)} (+ both
     for enc-dec). Returns (logits_or_hidden, new_caches, aux_loss); with
     head=False the final normed hidden states are returned (lm_loss fuses the
@@ -461,8 +450,7 @@ def forward(params, cfg: ArchConfig, inputs, scheme: str, seed: jax.Array,
     x, caches, aux = _run_stages(params, x, cfg, scheme, seed, mode=mode,
                                  caches=caches, pos=pos, positions=positions,
                                  active=active, block_table=block_table,
-                                 paged_kernel=paged_kernel,
-                                 unroll_stages=unroll_stages)
+                                 paged_kernel=paged_kernel)
     x = norm(x, params["final_norm"], cfg.norm, cfg.norm_eps)
     if not head:
         return x, caches, aux
@@ -473,8 +461,7 @@ def forward(params, cfg: ArchConfig, inputs, scheme: str, seed: jax.Array,
 def forward_prefix(params, cfg: ArchConfig, inputs, scheme: str,
                    seed: jax.Array, *, n_prefix: int, caches=None,
                    mode: str = "decode", pos=None, active=None,
-                   block_table=None, paged_kernel=False,
-                   unroll_stages=False):
+                   block_table=None, paged_kernel=False):
     """Early-exit forward: the first `n_prefix` layers + final norm + head.
 
     This is the self-speculative DRAFT stack (serve/spec_decode.py): it
@@ -498,8 +485,7 @@ def forward_prefix(params, cfg: ArchConfig, inputs, scheme: str,
                                      caches=caches, pos=pos,
                                      positions=positions, stages=specs,
                                      active=active, block_table=block_table,
-                                     paged_kernel=paged_kernel,
-                                     unroll_stages=unroll_stages)
+                                     paged_kernel=paged_kernel)
     x = norm(x, params["final_norm"], cfg.norm, cfg.norm_eps)
     logits = lm_head(x, head_weight(params, cfg), cfg.quantize_lm_head,
                      scheme, seed)

@@ -3,8 +3,9 @@
 `make_paged_serve_step` is THE engine step (serve/engine.py compiles it per
 chunk size; launch/dryrun lowers it for decode cells), and
 `make_sharded_serve_step` is its multi-host form — the same function body
-under a manual-"data" / auto-"model" `shard_map` (slot-affine pool slices,
-shard-local block tables; see serve/README.md "Multi-host serving").
+under a `shard_map` manual over "data" only, "model" left to GSPMD
+(slot-affine pool slices, shard-local block tables; see serve/README.md
+"Multi-host serving").
 `make_serve_step`/`greedy_generate` remain as the legacy dense-cache
 fixed-batch path (benchmarks' seed baseline, simple examples).
 
@@ -62,56 +63,42 @@ def make_sharded_serve_step(cfg, scheme: str, mesh, *,
         physical indices, so every gather/scatter the step performs resolves
         inside the local pool slice: the forward body runs UNCHANGED on
         local shapes, and no collective ever touches the pool.
-      auto over every other axis ("model", "pod") — weights stay under
-        GSPMD control, so `PackedQWeight` leaves placed with
+      GSPMD over every other axis ("model", "pod") — they are not in the
+        shard_map's `axis_names`, so `PackedQWeight` leaves placed with
         `dist.sharding.serve_param_shardings` compute row-split GEMMs with
         XLA-inserted activation reductions (activation-sized, not
-        pool-sized, wire).
+        pool-sized, wire). The Pallas attention kernels, which GSPMD cannot
+        partition, run manual over those axes too (kernels/ops.py).
 
     Exactness: the decode forward is row-local per slot (docs/CONVENTIONS.md
     records the contract), so with model=1 the emitted greedy streams are
     BITWISE identical to the single-host engine — tests/test_serve_sharded.py
-    pins this. check_rep is off: replication checking cannot see through the
-    auto axes.
-
-    When an auto axis is non-trivial (model > 1) the layer scan is fully
-    UNROLLED: this XLA CHECK-fails propagating shardings into a while body
-    inside a manual-subgroup region (lm._run_stages documents the failure).
+    pins this. check_vma is off: the replication check cannot see through
+    the GSPMD axes.
     """
     return shard_serve_step(
-        make_paged_serve_step(cfg, scheme, paged_kernel=paged_kernel,
-                              unroll_stages=_needs_unroll(mesh)), mesh)
-
-
-def _needs_unroll(mesh) -> bool:
-    """True when the mesh carries a non-trivial GSPMD `auto` axis (anything
-    but "data" with size > 1) — the configuration whose while-body sharding
-    propagation is broken; see make_sharded_serve_step."""
-    return any(ax != "data" and size > 1 for ax, size in dict(mesh.shape).items())
+        make_paged_serve_step(cfg, scheme, paged_kernel=paged_kernel), mesh)
 
 
 def shard_serve_step(step, mesh, *, out_batch_axis: int = 0):
     """shard_map-wrap any engine-step-signature function
     `(params, cache, table, tokens, pos, active) -> (out, cache)` with the
     standard serving specs: params replicated over the manual "data" axis
-    (every other mesh axis auto / GSPMD), cache leaves split on axis 1
+    (every other mesh axis left to GSPMD), cache leaves split on axis 1
     (block / slot homes), per-slot inputs split on axis 0, and `out` split
     on `out_batch_axis` (0 for (B, S, V) logits; the speculative propose
     scan passes 1 for its (K, B) token stack)."""
-    from repro import dist
-    auto = frozenset(a for a in mesh.axis_names if a != "data")
     P = jax.sharding.PartitionSpec
     out_spec = P(*([None] * out_batch_axis), "data")
-    return dist.shard_map(
+    return jax.shard_map(
         step, mesh=mesh,
         in_specs=(P(), P(None, "data"), P("data"), P("data"), P("data"),
                   P("data")),
         out_specs=(out_spec, P(None, "data")),
-        check_rep=False, auto=auto)
+        axis_names={"data"}, check_vma=False)
 
 
-def make_paged_serve_step(cfg, scheme: str, *, paged_kernel: bool = False,
-                          unroll_stages: bool = False):
+def make_paged_serve_step(cfg, scheme: str, *, paged_kernel: bool = False):
     """The ENGINE's decode step signature (per-slot position vector, active
     mask, block table, pool-shaped caches) — what launch/dryrun lowers for
     decode cells so the cost model prices the paged gather/scatter traffic
@@ -124,8 +111,7 @@ def make_paged_serve_step(cfg, scheme: str, *, paged_kernel: bool = False,
                                       jnp.asarray(_SEED), caches=cache,
                                       mode="decode", pos=pos, active=active,
                                       block_table=table,
-                                      paged_kernel=paged_kernel,
-                                      unroll_stages=unroll_stages)
+                                      paged_kernel=paged_kernel)
         return logits, cache
     return paged_serve_step
 

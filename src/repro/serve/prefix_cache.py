@@ -334,12 +334,17 @@ class PrefixCache:
             return 0
         t0 = self.wall()
         pool = self.pool
-        for n in missing:
-            payload = self._snapshot(n)
-            blk = pool.alloc_cache_block(shard)
-            pool.write_block_host(blk, payload)
-            n.blocks[shard] = blk
-            self._inflight.append(blk)
+        try:
+            for n in missing:
+                payload = self._snapshot(n)
+                blk = pool.alloc_cache_block(shard)
+                pool.write_block_host(blk, payload)
+                n.blocks[shard] = blk
+                self._inflight.append(blk)
+        except OutOfBlocks:
+            # the snapshots taken so far stay; keep the tier in budget
+            self._trim_host()
+            raise
         dt = self.wall() - t0
         self.stats["swapped_in_blocks"] += len(missing)
         self.stats["swapin_s"] += dt

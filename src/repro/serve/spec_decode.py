@@ -89,14 +89,12 @@ class DraftStack:
                 self.pool.caches,
                 SH.serve_cache_shardings(self.pool.caches, self.mesh))
         self.params = params  # engine-owned; already mesh-placed when sharded
-        from repro.serve.decode import _needs_unroll
-        self.unroll = self.mesh is not None and _needs_unroll(self.mesh)
         self._step_fns: dict[int, object] = {}
         self._propose_fns: dict[int, object] = {}
 
     def _wrap(self, fn, *, out_batch_axis: int = 0):
-        """Mesh mode: the draft's steps run under the same manual-"data" /
-        auto-"model" shard_map as the engine's (serve/decode.py)."""
+        """Mesh mode: the draft's steps run under the same shard_map as the
+        engine's, manual over "data" only (serve/decode.py)."""
         if self.mesh is None:
             return fn
         from repro.serve.decode import shard_serve_step
@@ -114,7 +112,7 @@ class DraftStack:
         fn = self._propose_fns.get(k)
         if fn is None:
             cfg, scheme, npfx = self.cfg, self.econf.scheme, self.n_prefix
-            pk, unroll = self.paged_kernel, self.unroll
+            pk = self.paged_kernel
 
             def prop_fn(params, caches, table, tok0, pos, active):
                 def body(carry, t):
@@ -123,16 +121,12 @@ class DraftStack:
                         params, cfg, {"tokens": cur[:, None]}, scheme, _SEED,
                         n_prefix=npfx, caches=caches, mode="decode",
                         pos=pos + t, active=active, block_table=table,
-                        paged_kernel=pk, unroll_stages=unroll)
+                        paged_kernel=pk)
                     nxt = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
                     return (caches, nxt), nxt
 
-                # the propose loop is itself a scan: unroll it too under a
-                # non-trivial auto axis (same while-body sharding limitation
-                # as the layer scan — see lm._run_stages)
                 (caches, _), toks = jax.lax.scan(
-                    body, (caches, tok0), jnp.arange(k),
-                    unroll=k if unroll else 1)
+                    body, (caches, tok0), jnp.arange(k))
                 return toks, caches
 
             fn = self._propose_fns[k] = jax.jit(
@@ -147,14 +141,13 @@ class DraftStack:
         fn = self._step_fns.get(size)
         if fn is None:
             cfg, scheme, npfx = self.cfg, self.econf.scheme, self.n_prefix
-            pk, unroll = self.paged_kernel, self.unroll
+            pk = self.paged_kernel
 
             def step_fn(params, caches, table, tokens, pos, active):
                 logits, caches, _ = lm.forward_prefix(
                     params, cfg, {"tokens": tokens}, scheme, _SEED,
                     n_prefix=npfx, caches=caches, mode="decode", pos=pos,
-                    active=active, block_table=table, paged_kernel=pk,
-                    unroll_stages=unroll)
+                    active=active, block_table=table, paged_kernel=pk)
                 return logits, caches
 
             fn = self._step_fns[size] = jax.jit(

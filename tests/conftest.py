@@ -22,6 +22,14 @@ if "--xla_force_host_platform_device_count" not in os.environ.get(
     os.environ["XLA_FLAGS"] = (
         os.environ.get("XLA_FLAGS", "")
         + " --xla_force_host_platform_device_count=2").strip()
+# Round every bf16 op to bf16. With excess precision allowed, XLA:CPU keeps
+# bf16 intermediates in f32 inside a fusion, so where a result is rounded
+# depends on how the program was fused: the shard_map'd serving step and the
+# one-host step then differ by bf16 ulps (an MLA layer's residual + MLP),
+# which MoE routing turns into different tokens. The bitwise suites compare
+# differently fused programs, so they need the rounding pinned.
+if "--xla_allow_excess_precision" not in os.environ["XLA_FLAGS"]:
+    os.environ["XLA_FLAGS"] += " --xla_allow_excess_precision=false"
 
 import jax
 import numpy as np

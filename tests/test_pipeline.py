@@ -29,7 +29,7 @@ def test_gpipe_matches_sequential():
         import os
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
         import jax, jax.numpy as jnp, numpy as np
-        from repro.dist import shard_map  # version-compat wrapper
+        from jax import shard_map
         from jax.sharding import Mesh, PartitionSpec as P
         from repro.dist.pipeline import gpipe
 
@@ -66,7 +66,7 @@ def test_gpipe_compressed_boundary():
         import os
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
         import jax, jax.numpy as jnp, numpy as np
-        from repro.dist import shard_map  # version-compat wrapper
+        from jax import shard_map
         from jax.sharding import Mesh, PartitionSpec as P
         from repro.dist.pipeline import gpipe
 

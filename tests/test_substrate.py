@@ -185,7 +185,7 @@ class TestGradCompression:
             import os
             os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
             import jax, jax.numpy as jnp, numpy as np
-            from repro.dist import shard_map  # version-compat wrapper
+            from jax import shard_map
             from jax.sharding import Mesh, PartitionSpec as P
             from repro.dist.compression import compressed_psum_mean
             mesh = Mesh(np.asarray(jax.devices()), ("data",))
