@@ -30,6 +30,7 @@ from repro.core import ms_eden as ME
 from repro.core import quant as Q
 from repro.core import rht as R
 from repro.core import schemes as S
+from repro.core import scopes as SC
 
 
 # --------------------------------------------------------------------------
@@ -116,27 +117,30 @@ def _block_values(qt: Q.QTensor) -> jax.Array:
 
 def _qmm(qa: Q.QTensor, qb: Q.QTensor) -> jax.Array:
     """Simulated NVFP4 GEMM: (Ma, D) x (Mb, D) -> (Ma, Mb) in fp32."""
-    a = _block_values(qa)
-    b = _block_values(qb)
-    out = jax.lax.dot_general(
-        a, b, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
-    return out * (qa.gscale * qb.gscale)
+    with jax.named_scope(SC.GEMM):
+        a = _block_values(qa)
+        b = _block_values(qb)
+        out = jax.lax.dot_general(
+            a, b, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+        return out * (qa.gscale * qb.gscale)
 
 
 def _mm(a: jax.Array, b: jax.Array) -> jax.Array:
     """bf16 GEMM (Ma, D) x (Mb, D) -> (Ma, Mb), fp32 accumulation."""
-    return jax.lax.dot_general(
-        a.astype(jnp.bfloat16), b.astype(jnp.bfloat16),
-        (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+    with jax.named_scope(SC.GEMM):
+        return jax.lax.dot_general(
+            a.astype(jnp.bfloat16), b.astype(jnp.bfloat16),
+            (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
 
 
 def _fwd_quant(x: jax.Array, kind: str) -> Q.QTensor:
-    if kind == "rtn":
-        return Q.quant_rtn(x, s=Q.S_EDEN)
-    if kind == "fos":
-        return Q.quant_four_over_six(x)
-    if kind == "square":
-        return Q.quant_square_block(x)
+    with jax.named_scope(SC.QUANT_FWD):
+        if kind == "rtn":
+            return Q.quant_rtn(x, s=Q.S_EDEN)
+        if kind == "fos":
+            return Q.quant_four_over_six(x)
+        if kind == "square":
+            return Q.quant_square_block(x)
     raise ValueError(f"unknown forward quantizer {kind}")
 
 
@@ -212,18 +216,24 @@ def _bwd_gemm(
 
     if bwd == "ms_eden":
         assert quant_a and quant_b and use_rht, "MS-EDEN requires re-quantizing both operands"
-        qa = ME.ms_eden(a, k_rht, k_a).qt
-        qb = ME.ms_eden(b, k_rht, k_b).qt
+        with jax.named_scope(SC.QUANT_BWD):
+            qa = ME.ms_eden(a, k_rht, k_a).qt
+            qb = ME.ms_eden(b, k_rht, k_b).qt
         return _qmm(qa, qb)  # rotations cancel along D
 
     quantizer = Q.quant_sr if bwd == "sr" else quant_sr_fos
-    ar = R.rht(a, k_rht) if (use_rht and quant_a and quant_b) else a
-    br = R.rht(b, k_rht) if (use_rht and quant_a and quant_b) else b
+    with jax.named_scope(SC.QUANT_BWD):
+        ar = R.rht(a, k_rht) if (use_rht and quant_a and quant_b) else a
+        br = R.rht(b, k_rht) if (use_rht and quant_a and quant_b) else b
+        if quant_a and quant_b:
+            qa, qb = quantizer(ar, k_a), quantizer(br, k_b)
+        elif quant_a:
+            ar = Q.dequant(quantizer(ar, k_a), jnp.bfloat16)
+        else:
+            br = Q.dequant(quantizer(br, k_b), jnp.bfloat16)
     if quant_a and quant_b:
-        return _qmm(quantizer(ar, k_a), quantizer(br, k_b))
-    if quant_a:
-        return _mm(Q.dequant(quantizer(ar, k_a), jnp.bfloat16), br)
-    return _mm(ar, Q.dequant(quantizer(br, k_b), jnp.bfloat16))
+        return _qmm(qa, qb)
+    return _mm(ar, br)
 
 
 # --------------------------------------------------------------------------

@@ -22,6 +22,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import formats as F
+from repro.core import scopes as SC
 
 
 @functools.lru_cache(maxsize=None)
@@ -52,19 +53,21 @@ def rht(x: jax.Array, key: jax.Array, b: int | None = None) -> jax.Array:
     to the sign diagonal (inverse = rht_inv)."""
     d = x.shape[-1]
     b = b or block_size(d)
-    s = sign_vector(key, b)
-    hm = jnp.asarray(hadamard(b))
-    xf = x.astype(jnp.float32).reshape(*x.shape[:-1], d // b, b)
-    out = (xf * s) @ hm
-    return out.reshape(x.shape)
+    with jax.named_scope(SC.RHT):
+        s = sign_vector(key, b)
+        hm = jnp.asarray(hadamard(b))
+        xf = x.astype(jnp.float32).reshape(*x.shape[:-1], d // b, b)
+        out = (xf * s) @ hm
+        return out.reshape(x.shape)
 
 
 def rht_inv(x: jax.Array, key: jax.Array, b: int | None = None) -> jax.Array:
     """Inverse blocked RHT (H^T then undo the sign diagonal)."""
     d = x.shape[-1]
     b = b or block_size(d)
-    s = sign_vector(key, b)
-    hm = jnp.asarray(hadamard(b))
-    xf = x.astype(jnp.float32).reshape(*x.shape[:-1], d // b, b)
-    out = (xf @ hm.T) * s
-    return out.reshape(x.shape)
+    with jax.named_scope(SC.RHT):
+        s = sign_vector(key, b)
+        hm = jnp.asarray(hadamard(b))
+        xf = x.astype(jnp.float32).reshape(*x.shape[:-1], d // b, b)
+        out = (xf @ hm.T) * s
+        return out.reshape(x.shape)

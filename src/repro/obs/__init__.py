@@ -1,8 +1,9 @@
 """Unified observability layer: metrics registry, request tracing,
 engine instrumentation, and NVFP4 quantization-health probes.
 
-Dependency-free by design (stdlib + the repo's own jax surface in the
-probe); see docs/CONVENTIONS.md §6 for the instrumentation boundary rule.
+Dependency-free by design (stdlib, jax's profiler annotations for host
+spans, and the repo's own jax surface in the probe); see
+docs/CONVENTIONS.md §6 for the instrumentation boundary rule.
 """
 
 from repro.obs.instrumentation import (NULL, Instrumentation,

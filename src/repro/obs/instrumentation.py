@@ -13,13 +13,19 @@ KV pool / prefix cache / scheduler / spec-decode paths it owns. It bundles
   - step-duration histograms split by `phase`: `dispatch` (host returned
     from enqueue) vs `synced` (device finished, cache writes included),
   - spec-decode acceptance histograms and pool/cache event counters,
-  - an optional NVFP4 quantization-health probe (obs/quant_probe.py).
+  - an optional NVFP4 quantization-health probe (obs/quant_probe.py),
+  - host spans (`span`) around the phases of a tick, on the profiler's
+    clock: `jax.profiler.TraceAnnotation`s, which record only while a
+    profiler session runs, so the device ops they explain sit on the same
+    timeline.
 
 Disabled mode: `EngineConfig(obs=None)` resolves to the `NULL` sentinel —
 a slotted singleton whose only attribute is `enabled = False`. Every engine
 hook site guards with `if self.obs.enabled:` so the disabled hot path costs
 one attribute read and allocates NOTHING (no trace objects, no metric
-children, no dict churn); tests/test_obs.py pins both properties.
+children, no dict churn); tests/test_obs.py pins both properties. Spans are
+bound once per engine (`span_fn`): disabled, every span is one call that
+returns the shared `NO_SPAN` context.
 
 One `Instrumentation` serves ONE engine (trace lifecycles and the stats
 view are per-engine state). Point several engines' Instrumentation at a
@@ -31,6 +37,8 @@ from __future__ import annotations
 
 import itertools
 from collections.abc import MutableMapping
+
+from jax.profiler import TraceAnnotation
 
 from repro.obs import tracing
 from repro.obs.metrics import MetricsRegistry, default_registry
@@ -64,6 +72,36 @@ class NullInstrumentation:
 
 
 NULL = NullInstrumentation()
+
+
+class _NoSpan:
+    """The context every disabled span returns: one shared instance, and
+    explicit `__exit__` arguments (a `*args` signature would build a tuple
+    per exit)."""
+
+    __slots__ = ()
+
+    def __enter__(self):
+        return None
+
+    def __exit__(self, typ, val, tb):
+        return False
+
+
+NO_SPAN = _NoSpan()
+
+
+def no_span(name, queued=0, tokens=0, rows=0, rebuilt=False):
+    """Disabled `Instrumentation.span`. The span attributes are named
+    parameters, not `**attrs`, so a call builds no keyword dict."""
+    return NO_SPAN
+
+
+def span_fn(obs):
+    """The span function a component binds once: `obs.span` when `obs` is
+    enabled, else `no_span`."""
+    return obs.span if obs.enabled else no_span
+
 
 _ENGINE_IDS = itertools.count()
 
@@ -211,6 +249,17 @@ class Instrumentation:
             "frontend-side rejections by reason (backpressure | "
             "rate_limited | budget_exhausted | draining)",
             labels=("reason",))
+
+    # ---- host spans ------------------------------------------------------
+
+    @staticmethod
+    def span(name: str, **attrs) -> TraceAnnotation:
+        """A host span named `name` (`serve.*` in the engine), its `attrs`
+        recorded as the event's stats. Records only while a profiler
+        session runs (`jax.profiler.trace`); otherwise it is a no-op
+        TraceMe. Host bookkeeping only: never open one inside a traced
+        function (docs/CONVENTIONS.md §6)."""
+        return TraceAnnotation(name, **attrs)
 
     # ---- engine.stats compatibility -------------------------------------
 

@@ -44,7 +44,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import ArchConfig
-from repro.obs.instrumentation import NULL, legacy_stats_dict
+from repro.obs.instrumentation import NULL, legacy_stats_dict, span_fn
 from repro.serve import decode as serve_decode
 from repro.serve import spec_decode
 from repro.serve.kv_pool import KVPool, OutOfBlocks
@@ -282,6 +282,9 @@ class ServeEngine:
         # observability: resolved FIRST so prequantization can report its
         # weight-quantization health through the probe
         self.obs = e.obs if e.obs is not None else NULL
+        # host spans around the tick's phases (`serve.*`), bound once:
+        # disabled, each is one call returning a shared no-op context
+        self._span = span_fn(self.obs)
         probe = self.obs.quant_probe if self.obs.enabled else None
         self.params = (prequantize(params, cfg, e.scheme, probe=probe)
                        if e.prequant else params)
@@ -562,22 +565,25 @@ class ServeEngine:
         Role-split engines run half a tick each: a prefill worker never
         decodes (finished prompts leave as Handoffs instead), a decode
         worker never prefills (Handoffs admit straight into DECODE)."""
-        self.stats["ticks"] += 1
-        self._admit()
-        if self.role != "decode":
-            self._prefill_tick()
-        finished = (self._handoff_tick() if self.role == "prefill"
-                    else self._decode_tick())
-        if self.cache is not None and self.cache.spill:
-            # tick-boundary host-tier work: bounded proactive replication of
-            # hot prefixes, then fold this tick's dispatched swap-ins into
-            # the plain cached-block accounting (the writes are ordered
-            # before any dependent step read — no sync here)
-            self.cache.replicate_hot()
-            self.cache.complete_swaps()
-        if self.obs.enabled:
-            self.obs.on_tick(self)  # occupancy / pool / cache gauges
-        return finished
+        with self._span("serve.tick"):
+            self.stats["ticks"] += 1
+            with self._span("serve.admit", queued=len(self.queue)):
+                self._admit()
+            if self.role != "decode":
+                self._prefill_tick()
+            finished = (self._handoff_tick() if self.role == "prefill"
+                        else self._decode_tick())
+            if self.cache is not None and self.cache.spill:
+                # tick-boundary host-tier work: bounded proactive replication
+                # of hot prefixes, then fold this tick's dispatched swap-ins
+                # into the plain cached-block accounting (the writes are
+                # ordered before any dependent step read — no sync here)
+                self.cache.replicate_hot()
+                self.cache.complete_swaps()
+            if self.obs.enabled:
+                with self._span("serve.gauges"):
+                    self.obs.on_tick(self)  # occupancy / pool / cache gauges
+            return finished
 
     def _admit(self) -> None:
         for r in self.queue:
@@ -585,7 +591,8 @@ class ServeEngine:
         if self.obs.enabled:
             # queue depth / aging / slack gauges — the policy object knows
             # its own urgency model, so IT reports (scheduler.py observe)
-            self.sched.observe(self.obs, self.queue, self.clock())
+            with self._span("serve.gauges"):
+                self.sched.observe(self.obs, self.queue, self.clock())
         if self.role == "decode":
             # Handoffs admit FIFO straight into DECODE: the KV is already
             # computed, so "admission" is commit + allocate + import the
@@ -820,7 +827,8 @@ class ServeEngine:
             # sync the draft CACHE writes too, not just the logits — an
             # async cache write landing after the timer stops would be
             # billed to whatever step happens to sync next
-            jax.block_until_ready((out, self.draft.pool.caches))
+            with self._span("serve.sync"):
+                jax.block_until_ready((out, self.draft.pool.caches))
             t_sync = self.clock() - t0
             self.stats["prefill_s"] += t_sync
             if self.obs.enabled:
@@ -829,50 +837,57 @@ class ServeEngine:
             return  # bounded work: one chunk per tick
         remaining = len(prompt) - slot.cursor
         size = e.prefill_chunk if remaining >= e.prefill_chunk else 1
-        chunk = prompt[slot.cursor: slot.cursor + size]
-        self.pool.ensure(i, slot.cursor + size)
-        tokens = np.zeros((e.n_slots, size), np.int32)
-        tokens[i] = chunk
-        pos = np.zeros((e.n_slots,), np.int32)
-        pos[i] = slot.cursor
-        active = np.zeros((e.n_slots,), bool)
-        active[i] = True
-        t0 = self.clock()
-        logits = self._forward(size, tokens, pos, active)
-        if self.draft is not None:
-            # the draft cache covers the prompt too: same chunk through
-            # the prefix stack (its layers recompute what the first
-            # draft_layers of the full forward just computed)
-            self.draft.pool.ensure(i, slot.cursor + size)
-            self.draft.forward(size, tokens, pos, active)
-        t_disp = self.clock() - t0
-        # sync logits AND the cache pytrees: blocking on logits alone lets
-        # the (donated, in-place) KV scatter complete asynchronously AFTER
-        # the timer stops, under-reporting prefill_s and leaking device
-        # time into whichever step syncs next
-        sync = [logits, self.pool.caches]
-        if self.draft is not None:
-            sync.append(self.draft.pool.caches)
-        jax.block_until_ready(sync)
-        t_sync = self.clock() - t0
-        self.stats["prefill_s"] += t_sync
-        self.stats["prefill_tokens"] += size
-        self.stats["prefill_steps"] += 1
-        if self.obs.enabled:
-            self.obs.on_prefill_step(t_disp, t_sync)
-        slot.cursor += size
-        slot.draft_len = slot.cursor
-        if slot.cursor == len(prompt):
-            # prompt fully cached: sample the first generated token from
-            # the logits of the prompt's last position
-            tok = int(self._sample(logits[:, -1])[i])
-            slot.state = DECODE
-            slot.length = len(prompt)
-            slot.last_tok = tok
-            slot.generated.append(tok)
+        with self._span("serve.prefill", tokens=size):
+            with self._span("serve.inputs"):
+                chunk = prompt[slot.cursor: slot.cursor + size]
+                self.pool.ensure(i, slot.cursor + size)
+                tokens = np.zeros((e.n_slots, size), np.int32)
+                tokens[i] = chunk
+                pos = np.zeros((e.n_slots,), np.int32)
+                pos[i] = slot.cursor
+                active = np.zeros((e.n_slots,), bool)
+                active[i] = True
+            t0 = self.clock()
+            logits = self._forward(size, tokens, pos, active)
+            if self.draft is not None:
+                # the draft cache covers the prompt too: same chunk through
+                # the prefix stack (its layers recompute what the first
+                # draft_layers of the full forward just computed)
+                self.draft.pool.ensure(i, slot.cursor + size)
+                self.draft.forward(size, tokens, pos, active)
+            t_disp = self.clock() - t0
+            # sync logits AND the cache pytrees: blocking on logits alone
+            # lets the (donated, in-place) KV scatter complete
+            # asynchronously AFTER the timer stops, under-reporting
+            # prefill_s and leaking device time into whichever step syncs
+            # next
+            sync = [logits, self.pool.caches]
+            if self.draft is not None:
+                sync.append(self.draft.pool.caches)
+            with self._span("serve.sync"):
+                jax.block_until_ready(sync)
+            t_sync = self.clock() - t0
+            self.stats["prefill_s"] += t_sync
+            self.stats["prefill_tokens"] += size
+            self.stats["prefill_steps"] += 1
             if self.obs.enabled:
-                self.obs.on_first_token(slot.req, self.clock())
-            self._flush(i)
+                self.obs.on_prefill_step(t_disp, t_sync)
+            slot.cursor += size
+            slot.draft_len = slot.cursor
+            if slot.cursor == len(prompt):
+                # prompt fully cached: sample the first generated token
+                # from the logits of the prompt's last position
+                with self._span("serve.sample"):
+                    toks = self._sample(logits[:, -1])
+                with self._span("serve.readback"):
+                    tok = int(toks[i])
+                slot.state = DECODE
+                slot.length = len(prompt)
+                slot.last_tok = tok
+                slot.generated.append(tok)
+                if self.obs.enabled:
+                    self.obs.on_first_token(slot.req, self.clock())
+                self._flush(i)
         return  # bounded work: one chunk per tick
 
     def _retire_slot(self, i: int) -> RequestResult:
@@ -880,28 +895,29 @@ class ServeEngine:
         release pins and pool state (cache-insert-then-release ordering:
         insertion adds the cache's own ref while the blocks are still
         referenced; release only ever decrefs)."""
-        slot = self.slots[i]
-        res = RequestResult(
-            slot.req.req_id, list(slot.req.prompt),
-            list(slot.generated), arrival_s=slot.req.arrival_s,
-            finish_s=self.clock(),
-            deadline_s=slot.req.deadline_s)
-        if self.obs.enabled:
-            # closes the trace and surfaces queue-wait / TTFT /
-            # per-token decode latency on the result
-            self.obs.on_retire(slot.req, res, len(slot.generated),
-                               res.finish_s)
-        self._flush(i, res)
-        if self.cache is not None:
-            self.cache.insert(slot.req.prompt + slot.generated, i)
-            if slot.cache_nodes:
-                self.cache.release(slot.cache_nodes)
-        self.pool.release(i)
-        if self.draft is not None:
-            self.draft.pool.release(i)
-        self.slots[i] = _Slot()
-        self.stats["finished"] += 1
-        return res
+        with self._span("serve.retire"):
+            slot = self.slots[i]
+            res = RequestResult(
+                slot.req.req_id, list(slot.req.prompt),
+                list(slot.generated), arrival_s=slot.req.arrival_s,
+                finish_s=self.clock(),
+                deadline_s=slot.req.deadline_s)
+            if self.obs.enabled:
+                # closes the trace and surfaces queue-wait / TTFT /
+                # per-token decode latency on the result
+                self.obs.on_retire(slot.req, res, len(slot.generated),
+                                   res.finish_s)
+            self._flush(i, res)
+            if self.cache is not None:
+                self.cache.insert(slot.req.prompt + slot.generated, i)
+                if slot.cache_nodes:
+                    self.cache.release(slot.cache_nodes)
+            self.pool.release(i)
+            if self.draft is not None:
+                self.draft.pool.release(i)
+            self.slots[i] = _Slot()
+            self.stats["finished"] += 1
+            return res
 
     def _handoff_tick(self) -> list[RequestResult]:
         """Prefill-role half-tick: every slot whose prompt just finished
@@ -983,51 +999,58 @@ class ServeEngine:
             return finished
 
         if e.spec_k > 0:
+            with self._span("serve.decode", rows=len(dec)):
+                t0 = self.clock()
+                emitted = spec_decode.spec_round(self, dec)
+                t_disp = self.clock() - t0
+                # the whole cache pytree, not just the first leaf: truncate
+                # rewrites tables but layer caches past leaf 0 may still
+                # have in-flight scatters when the timer stops
+                with self._span("serve.sync"):
+                    jax.block_until_ready(self.pool.caches)
+                t_sync = self.clock() - t0
+                self.stats["decode_s"] += t_sync
+                self.stats["decode_tokens"] += emitted
+                self.stats["decode_steps"] += 1
+                if self.obs.enabled:
+                    self.obs.on_decode_step(t_disp, t_sync)
+                for i in dec:
+                    self._flush(i)
+            return finished
+
+        with self._span("serve.decode", rows=len(dec)):
+            with self._span("serve.inputs"):
+                tokens = np.zeros((e.n_slots, 1), np.int32)
+                pos = np.zeros((e.n_slots,), np.int32)
+                active = np.zeros((e.n_slots,), bool)
+                for i in dec:
+                    slot = self.slots[i]
+                    self.pool.ensure(i, slot.length + 1)
+                    tokens[i, 0] = slot.last_tok
+                    pos[i] = slot.length
+                    active[i] = True
             t0 = self.clock()
-            emitted = spec_decode.spec_round(self, dec)
+            logits = self._forward(1, tokens, pos, active)
+            with self._span("serve.sample"):
+                toks = self._sample(logits[:, -1])
             t_disp = self.clock() - t0
-            # the whole cache pytree, not just the first leaf: truncate
-            # rewrites tables but layer caches past leaf 0 may still have
-            # in-flight scatters when the timer stops
-            jax.block_until_ready(self.pool.caches)
+            # sync tokens AND cache writes (same leak as prefill: the
+            # donated cache scatter may outlive the token fetch)
+            with self._span("serve.sync"):
+                jax.block_until_ready((toks, self.pool.caches))
             t_sync = self.clock() - t0
             self.stats["decode_s"] += t_sync
-            self.stats["decode_tokens"] += emitted
+            self.stats["decode_tokens"] += len(dec)
             self.stats["decode_steps"] += 1
             if self.obs.enabled:
                 self.obs.on_decode_step(t_disp, t_sync)
             for i in dec:
+                slot = self.slots[i]
+                slot.length += 1
+                with self._span("serve.readback"):
+                    slot.last_tok = int(toks[i])
+                slot.generated.append(slot.last_tok)
                 self._flush(i)
-            return finished
-
-        tokens = np.zeros((e.n_slots, 1), np.int32)
-        pos = np.zeros((e.n_slots,), np.int32)
-        active = np.zeros((e.n_slots,), bool)
-        for i in dec:
-            slot = self.slots[i]
-            self.pool.ensure(i, slot.length + 1)
-            tokens[i, 0] = slot.last_tok
-            pos[i] = slot.length
-            active[i] = True
-        t0 = self.clock()
-        logits = self._forward(1, tokens, pos, active)
-        toks = self._sample(logits[:, -1])
-        t_disp = self.clock() - t0
-        # sync tokens AND cache writes (same leak as prefill: the donated
-        # cache scatter may outlive the token fetch)
-        jax.block_until_ready((toks, self.pool.caches))
-        t_sync = self.clock() - t0
-        self.stats["decode_s"] += t_sync
-        self.stats["decode_tokens"] += len(dec)
-        self.stats["decode_steps"] += 1
-        if self.obs.enabled:
-            self.obs.on_decode_step(t_disp, t_sync)
-        for i in dec:
-            slot = self.slots[i]
-            slot.length += 1
-            slot.last_tok = int(toks[i])
-            slot.generated.append(slot.last_tok)
-            self._flush(i)
         return finished
 
     def _flush(self, i: int, result: RequestResult | None = None) -> None:
@@ -1044,7 +1067,8 @@ class ServeEngine:
         new = s.generated[s.emitted:]
         if new or result is not None:
             s.emitted = len(s.generated)
-            self.token_hook(s.req, new, result)
+            with self._span("serve.flush"):
+                self.token_hook(s.req, new, result)
 
     # ------------------------------------------------------------------
     # jitted steps
@@ -1072,9 +1096,12 @@ class ServeEngine:
         # stacked (n_slots, 2, max_blocks) read/write tables: scatters go
         # through the write view, whose prefix-cache-aliased entries hold
         # the sentinel — shared blocks are never written (kv_pool.py)
-        logits, self.pool.caches = fn(
-            self.params, self.pool.caches, self.pool.tables_device(),
-            jnp.asarray(tokens), jnp.asarray(pos), jnp.asarray(active))
+        with self._span("serve.tables", rebuilt=self.pool.tables_stale):
+            tables = self.pool.tables_device()
+        with self._span("serve.dispatch"):
+            logits, self.pool.caches = fn(
+                self.params, self.pool.caches, tables,
+                jnp.asarray(tokens), jnp.asarray(pos), jnp.asarray(active))
         return logits
 
     def _spec_key(self, slot: int):

@@ -67,6 +67,7 @@ import threading
 from collections.abc import MutableMapping
 from dataclasses import dataclass, field, replace
 
+from repro.obs.instrumentation import span_fn
 from repro.serve.engine import (EngineConfig, QueueFull, Request,
                                 ServeEngine, Unservable)
 from repro.serve.sampling import SamplingParams
@@ -188,6 +189,7 @@ class EngineBridge:
         self.engine = engine
         self.clock = engine.clock
         self.obs = engine.obs
+        self._span = span_fn(self.obs)
         self.visibility_timeout_s = visibility_timeout_s
         self.idle_wait_s = idle_wait_s
         engine.token_hook = self._on_tokens
@@ -234,7 +236,8 @@ class EngineBridge:
         eng = self.engine
         try:
             while not self._stop:
-                self._drain_commands()
+                with self._span("serve.bridge.commands"):
+                    self._drain_commands()
                 if self._stop:
                     break
                 if eng.has_work():

@@ -733,6 +733,12 @@ class KVPool:
             self._table_dev = jnp.asarray(self._local_table_np())
         return self._table_dev
 
+    @property
+    def tables_stale(self) -> bool:
+        """True when the next `tables_device()` call rebuilds the tables
+        and uploads them (paged pools whose tables changed since)."""
+        return self.paged and self._tables_dev is None
+
     def tables_device(self):
         """Stacked (n_slots, 2, max_blocks) device tables (None when dense):
         [:, 0] the READ table, [:, 1] the WRITE table, in which every

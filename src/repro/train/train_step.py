@@ -18,6 +18,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
+from repro.core import scopes as SC
 from repro.models import lm
 from repro.optim import adamw, muon, schedules
 
@@ -81,10 +82,12 @@ def make_train_step(cfg, scheme: str, *, optimizer: str = "adamw",
         if grad_transform is not None:
             grads = grad_transform(grads, step_seed(base_seed ^ 0x5555, state.step))
 
-        grads, gnorm = adamw.clip_by_global_norm(grads, grad_clip)
-        lr = sched(state.step, base_lr=base_lr, total_steps=total_steps)
-        new_params, new_opt = opt_mod.update(
-            grads, state.opt, state.params, lr=lr, weight_decay=weight_decay)
+        with jax.named_scope(SC.OPTIMIZER):
+            grads, gnorm = adamw.clip_by_global_norm(grads, grad_clip)
+            lr = sched(state.step, base_lr=base_lr, total_steps=total_steps)
+            new_params, new_opt = opt_mod.update(
+                grads, state.opt, state.params, lr=lr,
+                weight_decay=weight_decay)
         metrics = {"loss": loss, "grad_norm": gnorm, "lr": lr}
         return TrainState(new_params, new_opt, state.step + 1), metrics
 

@@ -13,7 +13,11 @@ Acceptance (ISSUE 6):
   (e) the quantization-health probe reports finite per-site values with
       the paper's Table-1 ordering (MS-EDEN < plain SR relative MSE);
   (f) serve-layer hygiene: no print()/logging calls in src/repro/serve
-      (all reporting flows through the obs hook).
+      (all reporting flows through the obs hook);
+  (g) host spans: the engine's `serve.*` TraceAnnotations appear in a
+      profiler trace, nested tick > prefill/decode > sync, streams stay
+      bitwise equal while they record, and a disabled engine makes none;
+      the quartet2 train step carries its named scopes in HLO metadata.
 """
 
 import ast
@@ -30,7 +34,7 @@ from repro.models import lm
 from repro.obs import (NULL, STAT_FLOAT_KEYS, STAT_KEYS, Instrumentation,
                        MetricsRegistry, RequestTrace, TraceSink,
                        legacy_stats_dict)
-from repro.obs import tracing
+from repro.obs import instrumentation, tracing
 from repro.serve.engine import (EngineConfig, QueueFull, Request, ServeEngine)
 
 pytestmark = pytest.mark.obs
@@ -402,10 +406,18 @@ def _tokens(cfg, params, prompts, obs=None, **kw):
 
 
 class TestDisabledAndDeterminism:
-    def test_disabled_mode_is_null_sentinel(self):
+    def test_disabled_mode_is_null_sentinel(self, monkeypatch):
+        made = []
+
+        def counting(*a, **k):
+            made.append(a)
+            return instrumentation.NO_SPAN
+
+        monkeypatch.setattr(instrumentation, "TraceAnnotation", counting)
         cfg = _cfg()
         eng = _engine(cfg, _params(cfg))     # obs=None
         assert eng.obs is NULL
+        assert eng._span is instrumentation.no_span
         assert NULL.enabled is False
         assert type(eng.stats) is dict       # plain legacy dict, no view
         # the sentinel carries NOTHING: any accidental hook use fails loudly
@@ -416,6 +428,10 @@ class TestDisabledAndDeterminism:
         _, results = _tokens(cfg, _params(cfg), _prompts(cfg))
         assert all(r.ttft_s is None and r.queue_wait_s is None
                    for r in results)
+        assert made == []                    # no span object was made
+        _tokens(cfg, _params(cfg), _prompts(cfg),
+                obs=Instrumentation(registry=MetricsRegistry()))
+        assert made                          # control: enabled spans are
 
     def test_streams_bitwise_unchanged_paged(self):
         cfg, params = _cfg(), None
@@ -438,6 +454,90 @@ class TestDisabledAndDeterminism:
         traced, _ = _tokens(cfg, params, prompts, mesh=mesh,
                             obs=Instrumentation(registry=MetricsRegistry()))
         assert plain == traced
+
+
+# --------------------------------------------------------------------------
+# host spans on the profiler's clock, named scopes in the train step
+# --------------------------------------------------------------------------
+
+TICK_SPANS = {"serve.tick", "serve.admit", "serve.prefill", "serve.decode",
+              "serve.inputs", "serve.tables", "serve.dispatch",
+              "serve.sample", "serve.sync", "serve.readback", "serve.flush",
+              "serve.retire", "serve.gauges"}
+
+
+@pytest.fixture(scope="module")
+def traced_engine(tmp_path_factory):
+    """A tiny engine run under `jax.profiler.trace`: the `serve.*` events
+    of each host line as (name, start, end), and the streams."""
+    cfg = _cfg()
+    params = _params(cfg)
+    prompts = _prompts(cfg)
+    eng = _engine(cfg, params, obs=Instrumentation(registry=MetricsRegistry()))
+    eng.token_hook = lambda req, new, result: None
+    out = tmp_path_factory.mktemp("profile")
+    with jax.profiler.trace(str(out)):
+        ids = [eng.submit(Request(prompt=p, max_new=4)) for p in prompts]
+        res = {r.req_id: r for r in eng.run()}
+    path = next(out.rglob("*.xplane.pb"))
+    lines = []
+    for plane in jax.profiler.ProfileData.from_file(str(path)).planes:
+        for line in plane.lines:
+            evs = [(ev.name, ev.start_ns, ev.start_ns + ev.duration_ns)
+                   for ev in line.events if ev.name.startswith("serve.")]
+            if evs:
+                lines.append(evs)
+    return lines, [res[i].tokens for i in ids], (cfg, params, prompts)
+
+
+class TestHostSpans:
+    def test_spans_named_and_nested(self, traced_engine):
+        lines, _, _ = traced_engine
+        assert len(lines) == 1               # all on the engine's thread
+        evs = lines[0]
+        assert {n for n, _, _ in evs} == TICK_SPANS
+
+        def inside(ev, names):
+            return any(n in names and s <= ev[1] and ev[2] <= e
+                       for n, s, e in evs)
+
+        syncs = [ev for ev in evs if ev[0] == "serve.sync"]
+        assert syncs
+        assert all(inside(ev, {"serve.prefill", "serve.decode"})
+                   for ev in syncs)
+        assert all(inside(ev, {"serve.tick"}) for ev in evs
+                   if ev[0] in ("serve.prefill", "serve.decode"))
+
+    def test_streams_bitwise_equal_while_spans_record(self, traced_engine):
+        _, traced, (cfg, params, prompts) = traced_engine
+        plain, _ = _tokens(cfg, params, prompts)
+        assert traced == plain
+
+    def test_quartet2_train_step_hlo_carries_scopes(self, monkeypatch):
+        import contextlib
+        import re
+
+        from repro.core import scopes as SC
+        from repro.train.train_step import make_train_step
+        cfg = _cfg()
+        tok = jax.ShapeDtypeStruct((2, 64), np.int32)
+
+        def lowered():
+            init, step = make_train_step(cfg, "quartet2")  # a fresh trace
+            state = jax.eval_shape(init, jax.eval_shape(
+                lambda: lm.init(cfg, jax.random.PRNGKey(0))))
+            return jax.jit(step).lower(state, {"tokens": tok, "labels": tok})
+
+        low = lowered()
+        hlo = low.as_text(dialect="hlo", debug_info=True)
+        comps = {c for name in re.findall(r'op_name="([^"]*)"', hlo)
+                 for c in name.split("/")}
+        assert {SC.QUANT_FWD, SC.QUANT_BWD, SC.RHT, SC.GEMM,
+                SC.OPTIMIZER} <= comps
+        # scopes are metadata only: without them the module is the same
+        monkeypatch.setattr(jax, "named_scope",
+                            lambda name: contextlib.nullcontext())
+        assert lowered().as_text() == low.as_text()
 
 
 # --------------------------------------------------------------------------
