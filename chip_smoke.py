@@ -167,8 +167,8 @@ def kernel_phase(cfg, *, seed: int) -> dict:
     """The compiled paged-attention kernels (bf16 and NVFP4-packed pools)
     against float64 attention, at the model's head widths and the serving
     pool geometry, for decode (Sq=1) and prefill-chunk (Sq=16) queries."""
-    from repro.core import formats as F
     from repro.kernels import ops
+    from repro.serve.kv_pool import pack_pair, unpack_pair
 
     rng = np.random.RandomState(seed)
     h, kv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.hd
@@ -186,13 +186,12 @@ def kernel_phase(cfg, *, seed: int) -> dict:
         q = jnp.asarray(rng.randn(N_SLOTS, sq, h, hd), jnp.bfloat16)
         k, v = (jnp.asarray(rng.randn(n_blocks, 16, kv, hd), jnp.bfloat16)
                 for _ in range(2))
-        kq, vq = F.nvfp4_cache_encode(k), F.nvfp4_cache_encode(v)
+        kvq = pack_pair(k, v)       # the NVFP4 pool holds K and V together
         for pool, got, want in (
                 ("bf16", ops.paged_attention(q, k, v, table, pos),
                  attention_f64(q, k, v, table, pos)),
-                ("nvfp4", ops.paged_attention_q(q, *kq, *vq, table, pos),
-                 attention_f64(q, F.nvfp4_cache_decode(*kq),
-                               F.nvfp4_cache_decode(*vq), table, pos))):
+                ("nvfp4", ops.paged_attention_q(q, *kvq, table, pos),
+                 attention_f64(q, *unpack_pair(kvq), table, pos))):
             err = float(np.max(np.abs(np.asarray(got, np.float64) - want)))
             bound = KERNEL_ATTN_RTOL * float(np.max(np.abs(want)))
             log(f"[kernel] {pool} pool, Sq={sq}: max |kernel - float64| "

@@ -5,11 +5,12 @@ dense (B, MAXB*BS, ...) copy of each layer's pool — and then score against
 the FULL table capacity every step. This kernel consumes the pool-shaped
 leaves directly:
 
-  - the (B, MAXB) block table and the (B,) position vector are
-    SCALAR-PREFETCHED; each grid cell's BlockSpec index map resolves
-    `table[b, j]` on the fly, so the pipeline DMAs exactly one physical
-    pool block per (row, logical-block) cell and no gathered view ever
-    exists in HBM;
+  - the (B, MAXB) block table, the (B,) position vector and the layer
+    index are SCALAR-PREFETCHED; each grid cell's BlockSpec index map
+    resolves `(layer, table[b, j])` on the fly, so the pipeline DMAs
+    exactly one physical pool block per (row, logical-block) cell out of
+    the STACKED (L, P, BS, ...) pool the serving step's layer scan
+    carries: no gathered view, and no per-layer slice, ever exists in HBM;
   - the grid is (batch row, logical KV block) with the block axis
     innermost; a per-row online-softmax accumulator (m, l, acc) lives in
     VMEM scratch across the block sweep (flash-decode);
@@ -26,11 +27,11 @@ leaves directly:
 
 Two variants share the online-softmax update:
 
-  gqa  — q (B, Sq, H, hd) vs K/V pools (P, BS, KV, hd)/(P, BS, KV, vd);
+  gqa  — q (B, Sq, H, hd) vs K/V pools (L, P, BS, KV, hd)/(…, vd);
          grouped heads (rep = H // KV) broadcast over each KV head.
   mla  — absorbed-form latent decode: q_abs (B, Sq, H, lora) and
-         q_rope (B, Sq, H, rope) vs the SHARED cc (P, BS, lora) /
-         kc (P, BS, rope) pools; the score is q_abs·cc + q_rope·kc and
+         q_rope (B, Sq, H, rope) vs the SHARED cc (L, P, BS, lora) /
+         kc (L, P, BS, rope) pools; the score is q_abs·cc + q_rope·kc and
          the value readout is over cc itself (vd == lora != hd), so the
          kernel returns o_lat for the caller's w_uv absorption.
 
@@ -41,11 +42,12 @@ produce exact zeros (l == 0 guard), mirroring the reference path's
 gathered-zeros result.
 
 Each variant also has a `_q` twin consuming the NVFP4-quantized pool
-(`serve.kv_pool.PackedKV`): the packed-operand BlockSpecs DMA uint8 e2m1
-code pairs (d/2 bytes) plus uint8 e4m3 scale bits (d/16 bytes) per block —
-0.28125x the bf16 HBM bytes — and `_dequant_halves` decodes them
-block-wise in VMEM (arithmetic e2m1/e4m3 decode, no gathers) into even and
-odd feature halves, which meet the matching halves of q and of the output
+(`serve.kv_pool.PackedKVPair` for gqa, `PackedKV` leaves for mla): the
+packed-operand BlockSpecs DMA uint8 e2m1 code pairs (d/2 bytes) plus uint8
+e4m3 scale bits (d/16 bytes) per block — 0.28125x the bf16 HBM bytes — and
+`_dequant_pair` / `_dequant_halves` decode them block-wise in VMEM
+(arithmetic e2m1/e4m3 decode, no gathers) into even and odd feature
+halves, which meet the matching halves of q and of the output
 (split and re-interleaved by XLA outside the kernel) in the SAME online
 softmax sweep. Dequant is exact in f32, so `_q` kernel operands match the
 gather-then-decode reference bit-for-bit; only the order in which the two
@@ -121,6 +123,37 @@ def _dequant_halves(codes, scale_bits):
             (((2,), (1,)), ((0,), (0,))), preferred_element_type=jnp.float32)
     else:
         sc = jnp.dot(sc, expand, preferred_element_type=jnp.float32)
+    return _decode_e2m1(c & 0xF) * sc, _decode_e2m1(c >> 4) * sc
+
+
+def _dequant_pair(codes, scale_bits, bs: int):
+    """Dequantize one block of a `serve.kv_pool.PackedKVPair` in VMEM:
+    codes (KV, BS, hd) — per token and head K's e2m1 pairs in the low
+    hd/2 lanes, V's in the high — and scale bits (KV, 2*BS*G), G = hd/16
+    groups per token, K's BS tokens then V's, -> the EVEN and ODD feature
+    halves (KV, BS, hd) f32 of K (low lanes) and V (high lanes).
+
+    Each byte's scale is moved into place by one 0/1 matmul: the scale
+    row is broadcast over the block's tokens, masked to the token's own
+    lanes, and contracted against a map from (K or V, group) lanes to
+    code lanes. Every output sums exactly one nonzero term, and e4m3
+    values are bf16-representable, so this is exact (`_dequant_halves`)."""
+    c = codes.astype(jnp.int32)
+    kv, _, hd = c.shape
+    n = scale_bits.shape[-1]
+    g, half = n // (2 * bs), hd // 2
+    sc = _decode_e4m3_bits(scale_bits)                  # (KV, 2*BS*G)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (bs, n), 1)
+    tok = jax.lax.broadcasted_iota(jnp.int32, (bs, n), 0)
+    mine = (lane % (bs * g)) // g == tok                # lane holds token's
+    sc = jnp.where(mine[None], sc[:, None, :], 0.0)     # (KV, BS, 2*BS*G)
+    m = jax.lax.broadcasted_iota(jnp.int32, (n, hd), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (n, hd), 1)
+    place = (((m >= bs * g) == (col >= half))
+             & (m % g == (col % half) // (F.GROUP // 2))).astype(jnp.float32)
+    sc = jax.lax.dot_general(
+        sc, jnp.broadcast_to(place, (kv, n, hd)),
+        (((2,), (1,)), ((0,), (0,))), preferred_element_type=jnp.float32)
     return _decode_e2m1(c & 0xF) * sc, _decode_e2m1(c >> 4) * sc
 
 
@@ -225,7 +258,7 @@ def _gqa_sweep(table_ref, pos_ref, q_refs, o_refs, m_ref, l_ref, acc_ref,
             o_ref[0] = o.transpose(1, 0, 2, 3).reshape(sq, h, -1)
 
 
-def _gqa_kernel(table_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
+def _gqa_kernel(table_ref, pos_ref, layer_ref, q_ref, k_ref, v_ref, o_ref,
                 m_ref, l_ref, acc_ref, *, bs: int, sentinel: int,
                 window: int | None, sqrt_hd: float):
     _gqa_sweep(table_ref, pos_ref, (q_ref,), (o_ref,), m_ref, l_ref, acc_ref,
@@ -235,21 +268,22 @@ def _gqa_kernel(table_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
                sqrt_hd=sqrt_hd)
 
 
-def _gqa_q_kernel(table_ref, pos_ref, qe_ref, qo_ref, kc_ref, ks_ref, vc_ref,
-                  vs_ref, oe_ref, oo_ref, m_ref, l_ref, acc_ref, *, bs: int,
+def _gqa_q_kernel(table_ref, pos_ref, layer_ref, qe_ref, qo_ref, c_ref,
+                  s_ref, oe_ref, oo_ref, m_ref, l_ref, acc_ref, *, bs: int,
                   sentinel: int, window: int | None, sqrt_hd: float):
-    """Packed-operand twin: K/V arrive as e2m1 code pairs + e4m3 scale bits
-    and dequantize in VMEM only for live cells, as even/odd halves that
-    meet q's halves (and fill the output's) split outside the kernel."""
+    """Packed-operand twin over a `PackedKVPair` block: each head's K and V
+    code pairs share one lane row and dequantize in VMEM, only for live
+    cells, into even/odd feature halves of that row (`_dequant_pair`).
+    q's halves arrive zero over V's lanes, so the scores contract K's
+    lanes alone, and the value readout lands in V's lanes; the caller
+    drops the output's K lanes."""
     def load_kv():
-        def halves(c_ref, s_ref):           # (BS, KV, .) -> (KV, BS, .)
-            return _dequant_halves(c_ref[0].astype(jnp.int32).transpose(1, 0, 2),
-                                   s_ref[0].astype(jnp.int32).transpose(1, 0, 2))
-        ks = [k.transpose(0, 2, 1) for k in halves(kc_ref, ks_ref)]
-        return ks, list(halves(vc_ref, vs_ref))
+        c = c_ref[0].astype(jnp.int32).transpose(1, 0, 2)  # (KV, BS, hd)
+        halves = _dequant_pair(c, s_ref[0], bs)
+        return [x.transpose(0, 2, 1) for x in halves], list(halves)
 
     _gqa_sweep(table_ref, pos_ref, (qe_ref, qo_ref), (oe_ref, oo_ref),
-               m_ref, l_ref, acc_ref, load_kv, kv=kc_ref.shape[2], bs=bs,
+               m_ref, l_ref, acc_ref, load_kv, kv=c_ref.shape[2], bs=bs,
                sentinel=sentinel, window=window, sqrt_hd=sqrt_hd)
 
 
@@ -296,8 +330,8 @@ def _mla_sweep(table_ref, pos_ref, qa_refs, qr_refs, o_refs,
             o_ref[0] = acc_ref[i] / l
 
 
-def _mla_kernel(table_ref, pos_ref, qa_ref, qr_ref, cc_ref, kc_ref, o_ref,
-                m_ref, l_ref, acc_ref, *, bs: int, sentinel: int,
+def _mla_kernel(table_ref, pos_ref, layer_ref, qa_ref, qr_ref, cc_ref, kc_ref,
+                o_ref, m_ref, l_ref, acc_ref, *, bs: int, sentinel: int,
                 scale: float):
     _mla_sweep(table_ref, pos_ref, (qa_ref,), (qr_ref,), (o_ref,),
                m_ref, l_ref, acc_ref,
@@ -306,8 +340,8 @@ def _mla_kernel(table_ref, pos_ref, qa_ref, qr_ref, cc_ref, kc_ref, o_ref,
                bs=bs, sentinel=sentinel, scale=scale)
 
 
-def _mla_q_kernel(table_ref, pos_ref, qae_ref, qao_ref, qre_ref, qro_ref,
-                  ccc_ref, ccs_ref, kcc_ref, kcs_ref, oe_ref, oo_ref,
+def _mla_q_kernel(table_ref, pos_ref, layer_ref, qae_ref, qao_ref, qre_ref,
+                  qro_ref, ccc_ref, ccs_ref, kcc_ref, kcs_ref, oe_ref, oo_ref,
                   m_ref, l_ref, acc_ref, *, bs: int, sentinel: int,
                   scale: float):
     """Packed-operand twin: both latent pools arrive as NVFP4 bytes and
@@ -321,18 +355,28 @@ def _mla_q_kernel(table_ref, pos_ref, qae_ref, qao_ref, qre_ref, qro_ref,
 
 def _table_spec_index(sentinel):
     """Index map resolving the physical pool block from the prefetched table
-    (the whole point: the pipeline fetches `table[b, j]`, never a view).
+    (the whole point: the pipeline fetches `table[b, j]`, never a view) in
+    the prefetched layer of the STACKED pool, so the caller's layer scan
+    hands over its carried pool as it is and no per-layer slice exists.
     Sentinel entries clamp to the LAST pool block (sentinel - 1) — the
     cell's compute is predicated off, so the clamped fetch is a buffer
     revisit, not extra traffic."""
-    def index(b, j, table_ref, pos_ref):
-        return (jnp.minimum(table_ref[b, j], sentinel - 1), 0, 0, 0)
+    def index(b, j, table_ref, pos_ref, layer_ref):
+        return (layer_ref[0], jnp.minimum(table_ref[b, j], sentinel - 1),
+                0, 0, 0)
     return index
 
 
 def _row(*block):
     """BlockSpec of one batch row's full (Sq, H, d) slab."""
-    return pl.BlockSpec((1, *block), lambda i, j, t, p: (i, 0, 0, 0))
+    return pl.BlockSpec((1, *block), lambda i, j, *_: (i, 0, 0, 0))
+
+
+def _pool_spec(block, index):
+    """BlockSpec of one pool block `block` = (BS, ...) of one layer, the
+    layer axis squeezed out: the kernel body sees (1, BS, ...)."""
+    n = len(block) + 2
+    return pl.BlockSpec((None, 1, *block), lambda *a: index(*a)[:n])
 
 
 def _gqa_grid_spec(b, maxb, sq, h, kv, q_dims, pool_blocks, n_blocks,
@@ -343,10 +387,10 @@ def _gqa_grid_spec(b, maxb, sq, h, kv, q_dims, pool_blocks, n_blocks,
     idx = _table_spec_index(n_blocks)
     rep = h // kv
     return pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(b, maxb),
         in_specs=[_row(sq, h, d) for d in q_dims]
-                 + [pl.BlockSpec(blk, idx) for blk in pool_blocks],
+                 + [_pool_spec(blk, idx) for blk in pool_blocks],
         out_specs=[_row(sq, h, vdp)] * n_parts,
         scratch_shapes=[
             pltpu.VMEM((kv, sq, rep), jnp.float32),
@@ -356,63 +400,62 @@ def _gqa_grid_spec(b, maxb, sq, h, kv, q_dims, pool_blocks, n_blocks,
     )
 
 
-def paged_gqa_call(q, k_pool, v_pool, table, pos, *, window: int | None,
-                   interpret: bool):
+def paged_gqa_call(q, k_pool, v_pool, table, pos, layer, *,
+                   window: int | None, interpret: bool):
+    """GQA flash-decode over layer `layer` ((1,) int32) of the stacked
+    pools k_pool (L, P, BS, KV, hd) / v_pool (L, P, BS, KV, vd)."""
     b, sq, h, hd = q.shape
-    n_blocks, bs, kv = k_pool.shape[0], k_pool.shape[1], k_pool.shape[2]
-    vd = v_pool.shape[3]
+    n_blocks, bs, kv = k_pool.shape[1], k_pool.shape[2], k_pool.shape[3]
+    vd = v_pool.shape[4]
     sqrt_hd = float(np.sqrt(np.float32(hd)))  # matches decode_sdpa's divisor
     grid_spec = _gqa_grid_spec(b, table.shape[1], sq, h, kv, [hd],
-                               [(1, bs, kv, hd), (1, bs, kv, vd)], n_blocks,
-                               vd, 1)
+                               [(bs, kv, hd), (bs, kv, vd)], n_blocks, vd, 1)
     (out,) = pl.pallas_call(
         functools.partial(_gqa_kernel, bs=bs, sentinel=n_blocks,
                           window=window, sqrt_hd=sqrt_hd),
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct((b, sq, h, vd), jnp.float32)],
         interpret=interpret,
-    )(table, pos, q, k_pool, v_pool)
+    )(table, pos, layer, q, k_pool, v_pool)
     return out
 
 
-def paged_gqa_q_call(q, k_codes, k_scales, v_codes, v_scales, table, pos, *,
+def paged_gqa_q_call(q, codes, scales, table, pos, layer, *,
                      window: int | None, interpret: bool):
-    """GQA flash-decode over the NVFP4-packed pool: same grid, same index
-    maps, but each pool operand is a (codes, scale-bits) uint8 pair whose
-    BlockSpecs move 0.28125x the bf16 bytes per cell. q enters, and the
-    output leaves, as even/odd feature halves (see `_dequant_halves`)."""
+    """GQA flash-decode over the NVFP4 `PackedKVPair` pool: same grid and
+    index maps as `paged_gqa_call`, over its two stacked leaves, codes
+    (L, P, BS, KV, hd) and scales (L, P, KV, 2*BS*hd/16), whose BlockSpecs
+    move 0.28125x the bf16 K+V bytes per cell. q enters, and the output
+    leaves, as even/odd feature halves (`_dequant_halves`), each padded
+    to the hd lanes of a K|V code row."""
     b, sq, h, hd = q.shape
-    n_blocks, bs, kv = k_codes.shape[0], k_codes.shape[1], k_codes.shape[2]
-    vdh = v_codes.shape[3]
+    n_blocks, bs, kv = codes.shape[1], codes.shape[2], codes.shape[3]
+    half = hd // 2
     sqrt_hd = float(np.sqrt(np.float32(hd)))  # matches decode_sdpa's divisor
     grid_spec = _gqa_grid_spec(
-        b, table.shape[1], sq, h, kv, [hd // 2] * 2,
-        [(1, bs, kv, hd // 2), (1, bs, kv, hd // F.GROUP),
-         (1, bs, kv, vdh), (1, bs, kv, 2 * vdh // F.GROUP)],
-        n_blocks, vdh, 2)
+        b, table.shape[1], sq, h, kv, [hd] * 2,
+        [(bs, kv, hd), (kv, scales.shape[-1])], n_blocks, hd, 2)
+    pad = [(0, 0)] * 3 + [(0, half)]
     oe, oo = pl.pallas_call(
         functools.partial(_gqa_q_kernel, bs=bs, sentinel=n_blocks,
                           window=window, sqrt_hd=sqrt_hd),
         grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((b, sq, h, vdh), jnp.float32)] * 2,
+        out_shape=[jax.ShapeDtypeStruct((b, sq, h, hd), jnp.float32)] * 2,
         interpret=interpret,
-    )(table, pos, *_split_halves(q), k_codes, k_scales, v_codes, v_scales)
-    return _interleave_halves(oe, oo)
+    )(table, pos, layer, *(jnp.pad(x, pad) for x in _split_halves(q)),
+      codes, scales)
+    return _interleave_halves(oe[..., half:], oo[..., half:])
 
 
 def _mla_grid_spec(b, maxb, sq, h, q_dims, pool_blocks, n_blocks, lorap,
                    n_parts):
-    """MLA twin of `_gqa_grid_spec` (3-D shared latent pools)."""
+    """MLA twin of `_gqa_grid_spec` (shared latent pools, one feature axis)."""
     idx = _table_spec_index(n_blocks)
-
-    def pool_idx3(i, j, t, p):
-        return idx(i, j, t, p)[:3]
-
     return pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(b, maxb),
         in_specs=[_row(sq, h, d) for d in q_dims]
-                 + [pl.BlockSpec(blk, pool_idx3) for blk in pool_blocks],
+                 + [_pool_spec(blk, idx) for blk in pool_blocks],
         out_specs=[_row(sq, h, lorap)] * n_parts,
         scratch_shapes=[
             pltpu.VMEM((sq, h), jnp.float32),
@@ -422,34 +465,36 @@ def _mla_grid_spec(b, maxb, sq, h, q_dims, pool_blocks, n_blocks, lorap,
     )
 
 
-def paged_mla_call(q_abs, q_rope, cc_pool, kc_pool, table, pos, *,
+def paged_mla_call(q_abs, q_rope, cc_pool, kc_pool, table, pos, layer, *,
                    scale: float, interpret: bool):
+    """Absorbed-form MLA flash-decode over layer `layer` of the stacked
+    latent pools cc_pool (L, P, BS, lora) / kc_pool (L, P, BS, rope)."""
     b, sq, h, lora = q_abs.shape
     rope = q_rope.shape[3]
-    n_blocks, bs = cc_pool.shape[0], cc_pool.shape[1]
+    n_blocks, bs = cc_pool.shape[1], cc_pool.shape[2]
     grid_spec = _mla_grid_spec(b, table.shape[1], sq, h, [lora, rope],
-                               [(1, bs, lora), (1, bs, rope)], n_blocks,
-                               lora, 1)
+                               [(bs, lora), (bs, rope)], n_blocks, lora, 1)
     (out,) = pl.pallas_call(
         functools.partial(_mla_kernel, bs=bs, sentinel=n_blocks, scale=scale),
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct((b, sq, h, lora), jnp.float32)],
         interpret=interpret,
-    )(table, pos, q_abs, q_rope, cc_pool, kc_pool)
+    )(table, pos, layer, q_abs, q_rope, cc_pool, kc_pool)
     return out
 
 
 def paged_mla_q_call(q_abs, q_rope, cc_codes, cc_scales, kc_codes, kc_scales,
-                     table, pos, *, scale: float, interpret: bool):
-    """Absorbed-form MLA flash-decode over NVFP4-packed latent pools (q and
-    the output as even/odd feature halves, as in `paged_gqa_q_call`)."""
+                     table, pos, layer, *, scale: float, interpret: bool):
+    """Absorbed-form MLA flash-decode over NVFP4-packed stacked latent pools
+    (q and the output as even/odd feature halves, as in
+    `paged_gqa_q_call`)."""
     b, sq, h, lora = q_abs.shape
     rope = q_rope.shape[3]
-    n_blocks, bs = cc_codes.shape[0], cc_codes.shape[1]
+    n_blocks, bs = cc_codes.shape[1], cc_codes.shape[2]
     grid_spec = _mla_grid_spec(
         b, table.shape[1], sq, h, [lora // 2] * 2 + [rope // 2] * 2,
-        [(1, bs, lora // 2), (1, bs, lora // F.GROUP),
-         (1, bs, rope // 2), (1, bs, rope // F.GROUP)],
+        [(bs, lora // 2), (bs, lora // F.GROUP),
+         (bs, rope // 2), (bs, rope // F.GROUP)],
         n_blocks, lora // 2, 2)
     oe, oo = pl.pallas_call(
         functools.partial(_mla_q_kernel, bs=bs, sentinel=n_blocks,
@@ -458,6 +503,6 @@ def paged_mla_q_call(q_abs, q_rope, cc_codes, cc_scales, kc_codes, kc_scales,
         out_shape=[jax.ShapeDtypeStruct((b, sq, h, lora // 2),
                                         jnp.float32)] * 2,
         interpret=interpret,
-    )(table, pos, *_split_halves(q_abs), *_split_halves(q_rope),
+    )(table, pos, layer, *_split_halves(q_abs), *_split_halves(q_rope),
       cc_codes, cc_scales, kc_codes, kc_scales)
     return _interleave_halves(oe, oo)

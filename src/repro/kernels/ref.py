@@ -59,15 +59,14 @@ def paged_mla_attention_ref(q_abs, q_rope, cc_pool, kc_pool, table, pos, *,
     return jnp.einsum("bhqt,btl->bqhl", prob, cv.astype(jnp.float32))
 
 
-def paged_attention_q_ref(q, k_codes, k_scales, v_codes, v_scales, table,
-                          pos, *, window=None):
-    """Oracle for kernels.ops.paged_attention_q: decode the packed pools
-    to bf16 (exact — e2m1 x e4m3 products fit bf16) and run the bf16
-    reference path, so kernel and oracle see bit-identical operands."""
-    from repro.core.formats import nvfp4_cache_decode
-    return paged_attention_ref(q, nvfp4_cache_decode(k_codes, k_scales),
-                               nvfp4_cache_decode(v_codes, v_scales),
-                               table, pos, window=window)
+def paged_attention_q_ref(q, codes, scales, table, pos, *, window=None):
+    """Oracle for kernels.ops.paged_attention_q: decode the packed
+    `PackedKVPair` pool to bf16 K and V (exact — e2m1 x e4m3 products fit
+    bf16) and run the bf16 reference path, so kernel and oracle see
+    bit-identical operands."""
+    from repro.serve.kv_pool import PackedKVPair, unpack_pair
+    k, v = unpack_pair(PackedKVPair(codes, scales))
+    return paged_attention_ref(q, k, v, table, pos, window=window)
 
 
 def paged_mla_attention_q_ref(q_abs, q_rope, cc_codes, cc_scales, kc_codes,

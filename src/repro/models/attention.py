@@ -233,7 +233,8 @@ def gqa_apply(p, x, cfg, scheme, seed, layer, *, causal=True, window=None,
 
 
 def gqa_decode(p, x, cfg, scheme, seed, layer, cache_kv, pos, *, window=None,
-               active=None, block_table=None, paged_kernel=False):
+               active=None, block_table=None, paged_kernel=False,
+               pool_layer=None):
     """Cached decode / chunked-prefill step. x: (B, Sq, D) with Sq >= 1.
 
     pos: scalar or (B,) — absolute position of each row's first token
@@ -244,6 +245,10 @@ def gqa_decode(p, x, cfg, scheme, seed, layer, cache_kv, pos, *, window=None,
     block_table: (B, MAXB) int32 — when given, cache_kv holds POOL-shaped
       (P, BS, KV, hd) leaves and reads/writes go through the paged KV pool
       (serve/kv_pool.py); unallocated entries carry the pool's OOB sentinel.
+    pool_layer: with block_table, cache_kv holds the STACKED (L, P, BS, KV,
+      hd) pool leaves and this layer's are `pool_layer` among them: the
+      scatter and the kernel work on the stack in place, and the updated
+      stack is returned (models/lm.py carries it through the layer scan).
     paged_kernel: attend with the block-table flash-decode Pallas kernel
       (kernels/paged_attention.py) instead of materializing gather_view
       copies — O(row length) HBM traffic instead of O(table capacity).
@@ -259,7 +264,6 @@ def gqa_decode(p, x, cfg, scheme, seed, layer, cache_kv, pos, *, window=None,
     posb = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (b,))
     positions = posb[:, None] + jnp.arange(sq, dtype=jnp.int32)[None, :]
     q, k, v = _project_qkv(p, x, cfg, scheme, seed, layer, positions)
-    kc, vc = cache_kv
     valid = positions >= 0
     if active is not None:
         valid &= active[:, None]
@@ -270,26 +274,28 @@ def gqa_decode(p, x, cfg, scheme, seed, layer, cache_kv, pos, *, window=None,
         # shared blocks are provably never written (docs/CONVENTIONS.md §5).
         # A plain (B, MAXB) table is its own write view.
         rt, wt = KV.split_tables(block_table)
-        kc = KV.scatter_tokens(kc, wt, positions, k, valid)
-        vc = KV.scatter_tokens(vc, wt, positions, v, valid)
-        if paged_kernel:
-            from repro.kernels import ops as KOPS
-            if isinstance(kc, KV.PackedKV):
-                # NVFP4 pool: hand the kernel the raw packed leaves; it
-                # dequantizes block-wise in VMEM (kernels/paged_attention.py)
-                o = KOPS.paged_attention_q(q, kc.codes, kc.scales,
-                                           vc.codes, vc.scales, rt, posb,
-                                           window=window)
-            else:
-                o = KOPS.paged_attention(q, kc, vc, rt, posb,
-                                         window=window)
+        packed = isinstance(cache_kv, KV.PackedKVPair)
+        if packed:   # NVFP4 pool: K and V quantize into one pair of leaves
+            new_cache = KV.scatter_tokens(cache_kv, wt, positions, (k, v),
+                                          valid, pool_layer)
         else:
-            # gather_view dequantizes PackedKV pools to bf16 (exactly), so
+            new_cache = tuple(
+                KV.scatter_tokens(c, wt, positions, x, valid, pool_layer)
+                for c, x in zip(cache_kv, (k, v)))
+        if paged_kernel:
+            # a packed pool goes to the kernel as its raw leaves, which it
+            # dequantizes block-wise in VMEM (kernels/paged_attention.py)
+            from repro.kernels import ops as KOPS
+            kernel = KOPS.paged_attention_q if packed else KOPS.paged_attention
+            o = kernel(q, *new_cache, rt, posb, pool_layer, window=window)
+        else:
+            # gather_view dequantizes packed pools to bf16 (exactly), so
             # the reference path is storage-mode agnostic
-            o = decode_sdpa(q, KV.gather_view(kc, rt),
-                            KV.gather_view(vc, rt), posb,
-                            window=window)
+            views = (KV.gather_view(new_cache, rt, pool_layer) if packed else
+                     [KV.gather_view(c, rt, pool_layer) for c in new_cache])
+            o = decode_sdpa(q, *views, posb, window=window)
     else:
+        kc, vc = cache_kv
         cap = kc.shape[1]
         ring = window is not None and cap == window
         if ring and sq > 1:
@@ -306,6 +312,7 @@ def gqa_decode(p, x, cfg, scheme, seed, layer, cache_kv, pos, *, window=None,
         vc = vc.at[bi, idx].set(v.astype(vc.dtype), mode="drop")
         abs_pos = ring_abs_pos(posb, sq, cap) if ring else None
         o = decode_sdpa(q, kc, vc, posb, window=window, abs_pos=abs_pos)
+        new_cache = (kc, vc)
     if active is not None:
         # Inactive rows must not read cache memory: their stale contents are
         # layout-dependent (dense keeps retired sequences' K/V, the pool
@@ -315,4 +322,4 @@ def gqa_decode(p, x, cfg, scheme, seed, layer, cache_kv, pos, *, window=None,
         # (deterministic) token stream.
         o = o * active[:, None, None, None].astype(o.dtype)
     out = qlinear(o.reshape(b, sq, -1), p["wo"], site_seed(seed, layer, 3), scheme)
-    return out, (kc, vc)
+    return out, new_cache

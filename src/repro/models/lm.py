@@ -14,7 +14,8 @@ trailing stage. Whisper (enc_dec) runs an encoder stack then a decoder stack
 with cross-attention.
 
 Decode caches are pytrees aligned with the stage structure (stacked on the
-same leading axis, consumed/emitted through the same scan). Sliding-window
+same leading axis, carried through the same scan and updated in place at
+each layer's index — docs/CONVENTIONS.md §10). Sliding-window
 layers use ring buffers of size `window` — the reason long_500k decode state
 stays O(window + d^2) for the hybrid/ssm archs.
 """
@@ -183,8 +184,12 @@ def _merge_state(active, new, old):
 
 def _apply_layer(spec, p, x, cfg, scheme, seed, layer_id, *, mode,
                  cache=None, pos=None, positions=None, enc_out=None,
-                 active=None, block_table=None, paged_kernel=False):
-    """One (mixer, ff) layer. Returns (x, new_cache_entry, aux)."""
+                 active=None, block_table=None, paged_kernel=False,
+                 pool_layer=None):
+    """One (mixer, ff) layer. Returns (x, new_cache_entry, aux).
+
+    Token kinds on the paged pool arrive stacked, to be read and written
+    at `pool_layer` in place (`_layer_cache_view`)."""
     mixer, ff = spec
     window = cfg.griffin.window if (cfg.griffin and mixer == "lattn") else None
     aux = jnp.zeros((), jnp.float32)
@@ -195,7 +200,8 @@ def _apply_layer(spec, p, x, cfg, scheme, seed, layer_id, *, mode,
             o, new_kv = A.gqa_decode(p["mix"], h, cfg, scheme, seed, layer_id,
                                      cache["kv"], pos, window=window,
                                      active=active, block_table=block_table,
-                                     paged_kernel=paged_kernel)
+                                     paged_kernel=paged_kernel,
+                                     pool_layer=pool_layer)
             cache = {**cache, "kv": new_kv}
         else:
             o, kv = A.gqa_apply(p["mix"], h, cfg, scheme, seed, layer_id,
@@ -208,7 +214,8 @@ def _apply_layer(spec, p, x, cfg, scheme, seed, layer_id, *, mode,
             o, new_c = M.mla_decode(p["mix"], h, cfg, scheme, seed, layer_id,
                                     cache["mla"], pos, active=active,
                                     block_table=block_table,
-                                    paged_kernel=paged_kernel)
+                                    paged_kernel=paged_kernel,
+                                    pool_layer=pool_layer)
             cache = {**cache, "mla": new_c}
         else:
             o, ckr = M.mla_apply(p["mix"], h, cfg, scheme, seed, layer_id,
@@ -368,44 +375,87 @@ def init(cfg: ArchConfig, key: jax.Array):
 REMAT = False
 
 
+def _in_place(kind, block_table) -> bool:
+    """Cache kinds the mixers update in place on the stage's stacked leaves:
+    the token kinds of the paged pool (serve/kv_pool.py scatters at
+    `pool.at[layer, blk, off]`, and the paged kernels read the stacked pool
+    at a prefetched layer index)."""
+    from repro.serve.kv_pool import TOKEN_KINDS   # kv_pool imports lm
+    return block_table is not None and kind in TOKEN_KINDS
+
+
+def _layer_cache_view(cache_l, idx, block_table):
+    """Layer `idx`'s cache entry from one pattern position's stacked leaves:
+    pool token kinds stay stacked; every other kind (dense caches,
+    recurrent state) is read as this layer's slice."""
+    return {k: v if _in_place(k, block_table) else jax.tree.map(
+                lambda a: jax.lax.dynamic_index_in_dim(a, idx, keepdims=False),
+                v)
+            for k, v in cache_l.items()}
+
+
+def _layer_cache_update(cache_l, new, idx, block_table):
+    """Inverse of `_layer_cache_view`: slices written back into the stacked
+    leaves at `idx` (XLA's in-place update of a loop carry); pool token
+    kinds come back from the mixer already updated."""
+    return {k: new[k] if _in_place(k, block_table) else jax.tree.map(
+                lambda a, n: jax.lax.dynamic_update_index_in_dim(a, n, idx, 0),
+                v, new[k])
+            for k, v in cache_l.items()}
+
+
 def _run_stages(params, x, cfg, scheme, seed, *, mode, caches=None,
                 pos=None, positions=None, enc_out=None, stages=None,
                 layer_offset=0, active=None, block_table=None,
                 paged_kernel=False):
+    """Run the layer stages. With `caches`, each stage's stacked cache is a
+    CARRY of its layer scan, so the donated serving pool is updated in
+    place: XLA cannot alias a scan's stacked outputs with its inputs, and
+    scanning over the cache would slice, copy and write back every layer's
+    pool into a second buffer (docs/CONVENTIONS.md §10)."""
     specs = stages if stages is not None else layer_specs(cfg)
     aux_total = jnp.zeros((), jnp.float32)
     new_caches = []
     off = layer_offset
     for si, (pattern, count) in enumerate(specs):
         sp = params["stages"][si]
-        cache_s = caches[si] if caches is not None else None
+
+        def layers(x, aux, idx, layer_p, cache_s):
+            for li, spec in enumerate(pattern):
+                lid = off + idx * len(pattern) + li
+                lk = f"l{li}"
+                c_in = (_layer_cache_view(cache_s[lk], idx, block_table)
+                        if cache_s is not None else None)
+                x, c_out, a = _apply_layer(
+                    spec, layer_p[lk], x, cfg, scheme, seed, lid,
+                    mode=mode, cache=c_in, pos=pos, positions=positions,
+                    enc_out=enc_out, active=active, block_table=block_table,
+                    paged_kernel=paged_kernel, pool_layer=idx)
+                if cache_s is not None:
+                    cache_s = {**cache_s, lk: _layer_cache_update(
+                        cache_s[lk], c_out, idx, block_table)}
+                aux = aux + a
+            return x, aux, cache_s
 
         def body(carry, inp):
             x, aux = carry
-            idx, layer_p, layer_c = inp
-            new_c = {} if layer_c is not None else None
-            for li, spec in enumerate(pattern):
-                lid = off + idx * len(pattern) + li
-                c_in = layer_c[f"l{li}"] if layer_c is not None else None
-                x, c_out, a = _apply_layer(
-                    spec, layer_p[f"l{li}"], x, cfg, scheme, seed, lid,
-                    mode=mode, cache=c_in, pos=pos, positions=positions,
-                    enc_out=enc_out, active=active, block_table=block_table,
-                    paged_kernel=paged_kernel)
-                if new_c is not None:
-                    new_c[f"l{li}"] = c_out
-                aux = aux + a
-            return (x, aux), new_c
+            x, aux, _ = layers(x, aux, *inp, None)
+            return (x, aux), None
+
+        def cached_body(carry, inp):
+            x, aux, cache_s = carry
+            return layers(x, aux, *inp, cache_s), None
 
         # remat on every differentiated path (train + the encoder stack that
         # feeds the decoder's training loss); decode/prefill have no backward
         fn = jax.checkpoint(body) if (REMAT and mode in ("train", "encode")) else body
-        if cache_s is None:
+        if caches is None:
             (x, aux_total), _ = jax.lax.scan(
-                fn, (x, aux_total), (jnp.arange(count), sp, None))
+                fn, (x, aux_total), (jnp.arange(count), sp))
         else:
-            (x, aux_total), new_cache_s = jax.lax.scan(
-                fn, (x, aux_total), (jnp.arange(count), sp, cache_s))
+            (x, aux_total, new_cache_s), _ = jax.lax.scan(
+                cached_body, (x, aux_total, caches[si]),
+                (jnp.arange(count), sp))
             new_caches.append(new_cache_s)
         off += count * len(pattern)
     return x, (new_caches if caches is not None else None), aux_total

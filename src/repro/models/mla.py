@@ -74,14 +74,16 @@ def mla_apply(p, x, cfg, scheme, seed, layer, *, positions=None):
 
 
 def mla_decode(p, x, cfg, scheme, seed, layer, cache, pos, *, active=None,
-               block_table=None, paged_kernel=False):
+               block_table=None, paged_kernel=False, pool_layer=None):
     """Absorbed-form decode over the latent cache. x: (B, Sq, D), Sq >= 1
     (Sq > 1 = chunked prefill).
 
     cache = (c: (B,Smax,kv_lora), kr: (B,Smax,rope)) — or pool-shaped
-    (P,BS,dim) leaves addressed through `block_table` (serve/kv_pool.py);
-    with `paged_kernel` the score/readout runs in the block-table
-    flash-decode Pallas kernel instead of over gather_view copies.
+    (P,BS,dim) leaves addressed through `block_table` (serve/kv_pool.py),
+    or with `pool_layer` the stacked (L,P,BS,dim) leaves, worked on in
+    place at that layer (see gqa_decode); with `paged_kernel` the
+    score/readout runs in the block-table flash-decode Pallas kernel
+    instead of over gather_view copies.
     pos: scalar or (B,) first-token position; active: (B,) write gate.
     score_h(t) = q_nope_h^T Wuk_h c_t + q_rope_h^T kr_t   (Wuk absorbed into q)
     out_h = (sum_t p_t c_t)^T Wuv_h                        (Wuv absorbed after)
@@ -119,22 +121,23 @@ def mla_decode(p, x, cfg, scheme, seed, layer, cache, pos, *, active=None,
         # read table for gathers/kernel, write view (aliased prefix entries
         # -> sentinel) for scatters — see gqa_decode / CONVENTIONS.md §5
         rt, wt = KV.split_tables(block_table)
-        cc = KV.scatter_tokens(cc, wt, positions, c_new, valid)
-        kc = KV.scatter_tokens(kc, wt, positions, kr2, valid)
+        cc = KV.scatter_tokens(cc, wt, positions, c_new, valid, pool_layer)
+        kc = KV.scatter_tokens(kc, wt, positions, kr2, valid, pool_layer)
         if paged_kernel:
             from repro.kernels import ops as KOPS
             if isinstance(cc, KV.PackedKV):
                 # NVFP4 latent pools: kernel dequantizes in VMEM
                 o_lat = KOPS.paged_mla_attention_q(
                     q_abs, q_rope, cc.codes, cc.scales, kc.codes, kc.scales,
-                    rt, posb, qk_dim=qk_dim)
+                    rt, posb, pool_layer, qk_dim=qk_dim)
             else:
                 o_lat = KOPS.paged_mla_attention(q_abs, q_rope, cc, kc,
-                                                 rt, posb, qk_dim=qk_dim)
+                                                 rt, posb, pool_layer,
+                                                 qk_dim=qk_dim)
             cv = None
         else:
-            cv = KV.gather_view(cc, rt)
-            kv = KV.gather_view(kc, rt)
+            cv = KV.gather_view(cc, rt, pool_layer)
+            kv = KV.gather_view(kc, rt, pool_layer)
     else:
         idx = jnp.where(valid, positions, cc.shape[1])  # OOB => write dropped
         bi = jnp.arange(b)[:, None]

@@ -44,7 +44,8 @@ write table whose shared-prefix entries hold the sentinel), so a scatter
 through them provably drops — docs/CONVENTIONS.md §5.
 
 The device-side primitives (`gather_view` / `scatter_tokens`) are called
-from the mixer decode paths (models/attention.py, models/mla.py); the
+from the mixer decode paths (models/attention.py, models/mla.py) on the
+stacked leaves at a layer index, in place (docs/CONVENTIONS.md §10); the
 `KVPool` class is the host-side allocator driven by the engine scheduler.
 
 With `paged=False` the pool builds dense per-slot caches (n_slots, max_len,
@@ -52,8 +53,9 @@ With `paged=False` the pool builds dense per-slot caches (n_slots, max_len,
 used as the reference layout in tests and by the legacy greedy loop.
 
 With `quantized=True` (paged only) every token-kind leaf is stored as NVFP4
-`PackedKV` bytes instead of bf16 — packed e2m1 codes + e4m3 group scales,
-0.28125x the HBM bytes — quantized per token at scatter time with
+bytes instead of bf16 — packed e2m1 codes + e4m3 group scales, 0.28125x the
+HBM bytes; a gqa / lattn layer's K and V together as one `PackedKVPair`,
+each mla latent leaf as a `PackedKV` — quantized per token at scatter time with
 deterministic RTN and dequantized either in the Pallas flash-decode kernel
 (kernels/paged_attention.py `*_q` entry points) or exactly in bf16 on the
 gather path. The bf16 pool remains the bitwise reference mode; quantized
@@ -72,6 +74,7 @@ import numpy as np
 
 from repro.configs.base import ArchConfig
 from repro.core import formats as F
+from repro.kernels import ops as KOPS
 from repro.models import griffin as G
 from repro.models import lm
 
@@ -104,6 +107,31 @@ class PackedKV(NamedTuple):
     scales: jax.Array  # uint8, (..., d // GROUP): e4m3 scale bits
 
 
+class PackedKVPair(NamedTuple):
+    """The K and V pools of one gqa / lattn layer, NVFP4-quantized TOGETHER
+    (`KVPool(quantized=True)`): the same bytes and the same per-token codec
+    as two `PackedKV` leaves, laid out so that no leaf ends in an axis of
+    hd/2 or hd/16 bytes.
+
+    A TPU stores an array whose last axis is not a whole number of
+    128-lane rows with the pool-block axis minor (that way it pads
+    nothing), while the paged kernel's operands are row-major: each
+    layer's read would relayout the pool. With a head dim that is a
+    multiple of 128 both leaves below are whole lane rows, the chip keeps
+    them row-major, and the serving step's layer scan reads and writes the
+    stacked pool in place (docs/CONVENTIONS.md §10).
+
+      codes:  (..., BS, KV, hd) — per token and head, K's packed e2m1
+              pairs (hd/2 bytes), then V's;
+      scales: (..., KV, 2 * BS * hd/16) — per head, the e4m3 scale bits of
+              the block's BS tokens for K (token t's hd/16 groups at lanes
+              t*hd/16 ...), then the same for V.
+    """
+
+    codes: jax.Array   # uint8, (..., BS, KV, hd): K pairs | V pairs
+    scales: jax.Array  # uint8, (..., KV, 2*BS*hd/16): K bits | V bits
+
+
 def reclaim_window(cfg: ArchConfig, specs=None) -> int | None:
     """Sliding window W when EVERY token-cache layer in `specs` is `lattn`.
 
@@ -125,10 +153,11 @@ def reclaim_window(cfg: ArchConfig, specs=None) -> int | None:
 # device-side primitives (used inside the jitted decode step)
 # --------------------------------------------------------------------------
 
-def gather_view(pool, table: jax.Array) -> jax.Array:
+def gather_view(pool, table: jax.Array, layer=None) -> jax.Array:
     """Materialize per-sequence logical views from the pool.
 
-    pool: (P, BS, ...); table: (B, MAXB) with OOB sentinel for unallocated.
+    pool: (P, BS, ...), or a stacked (L, P, BS, ...) leaf read at `layer`;
+    table: (B, MAXB) with OOB sentinel for unallocated.
     Returns (B, MAXB*BS, ...): each row's blocks in logical order, zeros for
     unallocated blocks (always masked downstream — attention only admits
     key positions <= the row's current position).
@@ -137,14 +166,53 @@ def gather_view(pool, table: jax.Array) -> jax.Array:
     (exact: e2m1 x e4m3 products fit bf16), so the dense/gather attention
     path consumes quantized pools with no mixer changes; unallocated blocks
     decode to exactly 0.0 (zero code x zero scale), preserving the fill
-    convention.
+    convention. A `PackedKVPair` gathers the same way into the (K, V)
+    pair of views.
     """
+    if isinstance(pool, PackedKVPair):
+        return _gather_pair(pool, table, layer)
     if isinstance(pool, PackedKV):
-        return F.nvfp4_cache_decode(gather_view(pool.codes, table),
-                                    gather_view(pool.scales, table))
+        return F.nvfp4_cache_decode(gather_view(pool.codes, table, layer),
+                                    gather_view(pool.scales, table, layer))
+    if layer is not None:
+        pool = jax.lax.dynamic_index_in_dim(pool, layer, keepdims=False)
     v = pool.at[table].get(mode="fill", fill_value=0)
     b, mb = table.shape
     return v.reshape(b, mb * pool.shape[1], *pool.shape[2:])
+
+
+def pack_pair(k: jax.Array, v: jax.Array) -> PackedKVPair:
+    """Quantize bf16 K and V pool blocks (..., BS, KV, hd) into one
+    `PackedKVPair` (the per-token codec of `scatter_tokens`)."""
+    (ck, sk), (cv, sv) = F.nvfp4_cache_encode(k), F.nvfp4_cache_encode(v)
+    *lead, bs, kv, g = sk.shape
+    sc = jnp.stack([sk, sv], axis=-4)                    # (..., 2, BS, KV, G)
+    sc = jnp.moveaxis(sc, -2, -4).reshape(*lead, kv, 2 * bs * g)
+    return PackedKVPair(jnp.concatenate([ck, cv], axis=-1), sc)
+
+
+def unpack_pair(pool: PackedKVPair) -> tuple[jax.Array, jax.Array]:
+    """Inverse of `pack_pair`: the bf16 (K, V) blocks (..., BS, KV, hd),
+    exact (e2m1 x e4m3 products fit bf16)."""
+    *lead, bs, kv, hd = pool.codes.shape
+    g = pool.scales.shape[-1] // (2 * bs)
+    sc = pool.scales.reshape(*lead, kv, 2, bs, g)
+    sc = jnp.moveaxis(sc, -4, -2)                        # (..., 2, BS, KV, G)
+    return (F.nvfp4_cache_decode(pool.codes[..., :hd // 2], sc[..., 0, :, :, :]),
+            F.nvfp4_cache_decode(pool.codes[..., hd // 2:], sc[..., 1, :, :, :]))
+
+
+def _gather_pair(pool: PackedKVPair, table: jax.Array, layer=None):
+    """`gather_view` of a `PackedKVPair`: the (K, V) bf16 views."""
+    if layer is not None:
+        pool = PackedKVPair(*(jax.lax.dynamic_index_in_dim(a, layer,
+                                                           keepdims=False)
+                              for a in pool))
+    b, mb = table.shape
+    blocks = PackedKVPair(*(a.at[table].get(mode="fill", fill_value=0)
+                            for a in pool))             # (B, MAXB, ...)
+    return tuple(x.reshape(b, mb * x.shape[2], *x.shape[3:])
+                 for x in unpack_pair(blocks))
 
 
 def split_tables(block_table: jax.Array) -> tuple[jax.Array, jax.Array]:
@@ -162,9 +230,12 @@ def split_tables(block_table: jax.Array) -> tuple[jax.Array, jax.Array]:
 
 
 def scatter_tokens(pool, table: jax.Array, positions: jax.Array,
-                   vals: jax.Array, valid: jax.Array):
+                   vals: jax.Array, valid: jax.Array, layer=None):
     """Write per-token values through the block table.
 
+    pool: (P, BS, ...), or a stacked (L, P, BS, ...) leaf written at
+    `pool.at[layer, blk, off]` — in place in the serving step's layer scan,
+    which carries the whole stacked pool (docs/CONVENTIONS.md §10);
     positions: (B, S) absolute token positions; vals: (B, S, ...);
     valid: (B, S) bool — rows/positions with valid=False (inactive slots,
     out-of-range positions) are routed to the OOB sentinel and dropped.
@@ -178,22 +249,63 @@ def scatter_tokens(pool, table: jax.Array, positions: jax.Array,
     last feature axis) and scatters codes and scale bits through the same
     block/offset indices — per-token groups make each position's packed
     bytes independent, so no block-level staging is needed and a block is
-    immutable packed bytes as soon as its positions are written.
+    immutable packed bytes as soon as its positions are written. A
+    `PackedKVPair` takes `vals` = (K, V) and writes both (`_scatter_pair`).
+
+    On a mesh the written values take the pool's placement first
+    (`kernels.ops.replicated`).
     """
+    vals = jax.tree.map(KOPS.replicated, vals)   # the pool's placement
+    if isinstance(pool, PackedKVPair):
+        return _scatter_pair(pool, table, positions, vals, valid, layer)
     if isinstance(pool, PackedKV):
         codes, scales = F.nvfp4_cache_encode(vals)
         return PackedKV(
-            scatter_tokens(pool.codes, table, positions, codes, valid),
-            scatter_tokens(pool.scales, table, positions, scales, valid))
-    n_blocks, bs = pool.shape[0], pool.shape[1]
-    b = table.shape[0]
+            scatter_tokens(pool.codes, table, positions, codes, valid, layer),
+            scatter_tokens(pool.scales, table, positions, scales, valid,
+                           layer))
+    lead = () if layer is None else (layer,)
+    blk, off = _block_offsets(pool.shape[len(lead):], table, positions,
+                              valid)
+    return pool.at[(*lead, blk, off)].set(vals.astype(pool.dtype),
+                                          mode="drop")
+
+
+def _block_offsets(shape, table, positions, valid):
+    """(physical block, offset) of each (B, S) position in a pool of one
+    layer's `shape` (P, BS, ...); invalid writes get the sentinel P."""
+    n_blocks, bs = shape[0], shape[1]
     valid = valid & (positions >= 0)
     logical = jnp.clip(positions, 0) // bs
-    blk = table.at[jnp.arange(b)[:, None], logical].get(
+    blk = table.at[jnp.arange(table.shape[0])[:, None], logical].get(
         mode="fill", fill_value=n_blocks)
     blk = jnp.where(valid, blk, n_blocks)  # OOB => scatter drops
-    off = jnp.clip(positions, 0) % bs
-    return pool.at[blk, off].set(vals.astype(pool.dtype), mode="drop")
+    return blk, jnp.clip(positions, 0) % bs
+
+
+def _scatter_pair(pool: PackedKVPair, table, positions, vals, valid,
+                  layer=None) -> PackedKVPair:
+    """`scatter_tokens` of `vals` = (K, V), each (B, S, KV, hd), into a
+    `PackedKVPair`: a token's codes are one (KV, hd) row of the codes
+    leaf, and its K and V scale bits two (KV, hd/16) windows of each
+    head's lane row in the scales leaf."""
+    (ck, sk), (cv, sv) = (F.nvfp4_cache_encode(x) for x in vals)
+    codes = scatter_tokens(pool.codes, table, positions,
+                           jnp.concatenate([ck, cv], axis=-1), valid, layer)
+    lead = () if layer is None else (layer,)
+    blk, off = _block_offsets(pool.codes.shape[len(lead):], table,
+                              positions, valid)
+    g, half = sk.shape[-1], pool.scales.shape[-1] // 2
+    kv = sk.shape[2]
+    # (B, S, 2, KV, G) element indices
+    lane = (jnp.arange(2)[:, None, None] * half
+            + jnp.arange(g)[None, None, :])[None, None] \
+        + (off * g)[:, :, None, None, None]
+    head = jnp.arange(kv)[None, None, None, :, None]
+    blk5 = blk[:, :, None, None, None]
+    scales = pool.scales.at[(*lead, blk5, head, lane)].set(
+        jnp.stack([sk, sv], axis=2), mode="drop")
+    return PackedKVPair(codes, scales)
 
 
 # --------------------------------------------------------------------------
@@ -207,14 +319,17 @@ def _layer_cache(spec, cfg: ArchConfig, n_slots: int, max_len: int, *,
     hd = cfg.hd
     c: dict[str, Any] = {}
 
+    def check_group(d):
+        if d % F.GROUP:
+            raise ValueError(
+                f"quantized KV pool needs feature dims divisible by "
+                f"{F.GROUP} (got {d} for mixer '{mixer}'): NVFP4 groups "
+                "lie along the last cache axis")
+
     def tok(*feat):
         if paged and quantized:
             d = feat[-1]
-            if d % F.GROUP:
-                raise ValueError(
-                    f"quantized KV pool needs feature dims divisible by "
-                    f"{F.GROUP} (got {d} for mixer '{mixer}'): NVFP4 groups "
-                    "lie along the last cache axis")
+            check_group(d)
             # zero codes x zero scale bits decode to exactly 0.0, matching
             # the bf16 pool's zero init / gather-fill convention
             return PackedKV(
@@ -229,7 +344,14 @@ def _layer_cache(spec, cfg: ArchConfig, n_slots: int, max_len: int, *,
         # uniform layout keeps dense/paged outputs bit-comparable.
         return jnp.zeros((n_slots, max_len, *feat), jnp.bfloat16)
 
-    if mixer in ("gqa", "lattn"):
+    if mixer in ("gqa", "lattn") and paged and quantized:
+        check_group(hd)
+        kv = cfg.n_kv_heads
+        c["kv"] = PackedKVPair(
+            jnp.zeros((n_blocks, block_size, kv, hd), jnp.uint8),
+            jnp.zeros((n_blocks, kv, 2 * block_size * hd // F.GROUP),
+                      jnp.uint8))
+    elif mixer in ("gqa", "lattn"):
         c["kv"] = (tok(cfg.n_kv_heads, hd), tok(cfg.n_kv_heads, hd))
     elif mixer == "mla":
         m = cfg.mla
@@ -253,7 +375,8 @@ def init_cache(cfg: ArchConfig, n_slots: int, max_len: int, *, paged: bool,
 
     `specs` overrides lm.layer_specs(cfg) — used by the speculative DRAFT
     pool, whose cache covers only lm.prefix_specs(cfg, draft_layers).
-    `quantized` stores token kinds as NVFP4 `PackedKV` leaves."""
+    `quantized` stores token kinds as NVFP4 bytes: `PackedKVPair` for
+    gqa / lattn layers, a `PackedKV` per leaf for mla."""
     stages = []
     for pattern, count in (specs if specs is not None else lm.layer_specs(cfg)):
         one = {f"l{i}": _layer_cache(pattern[i], cfg, n_slots, max_len,

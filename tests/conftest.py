@@ -56,8 +56,9 @@ _SCHED_SUITES = {"test_scheduler.py"}
 # quantization-health probe): `-m obs` selects it, wired by path.
 _OBS_SUITES = {"test_obs.py"}
 
-# Quantized-KV-cache suite (NVFP4 cache codec, PackedKV pools, packed-operand
-# decode kernels, kv_quant engine parity): `-m kvq` selects it, wired by path.
+# Quantized-KV-cache suite (NVFP4 cache codec, PackedKV / PackedKVPair pools,
+# packed-operand decode kernels, kv_quant engine parity): `-m kvq` selects
+# it, wired by path.
 _KVQ_SUITES = {"test_kv_quant.py"}
 
 # Streaming-frontend suites (asyncio HTTP/SSE server, engine-thread bridge,

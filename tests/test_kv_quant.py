@@ -50,7 +50,8 @@ from repro.models import lm
 from repro.models.attention import decode_sdpa
 from repro.serve import kv_pool as KV
 from repro.serve.engine import EngineConfig, Request, ServeEngine
-from repro.serve.kv_pool import KVPool, PackedKV, gather_view, scatter_tokens
+from repro.serve.kv_pool import (KVPool, PackedKV, PackedKVPair, gather_view,
+                                 pack_pair, scatter_tokens, unpack_pair)
 
 ATOL, RTOL = 5e-6, 1e-5
 
@@ -188,6 +189,48 @@ class TestScatterTokens:
         got[0, 4:6] = 0.0
         assert np.abs(got).max() == 0.0   # masked write + everything else
 
+    @pytest.mark.parametrize("layer", [None, 1])
+    def test_pair_scatter_then_gather_round_trips(self, layer, np_rng):
+        """A `PackedKVPair` (K and V quantized together, scale bits in
+        per-head lane rows) holds exactly the bytes of packing the
+        bf16 pools written the same way: each token's codes and scale
+        windows land in its own block row, no other layer or block moves,
+        and the gather decodes both views exactly."""
+        n_blocks, bs, kv, hd, n_layers = 5, 4, 2, 32, 3
+        table = jnp.asarray([[3, 1, n_blocks], [0, 2, 4]], jnp.int32)
+        positions = jnp.asarray([[0, 1, 2, 3, 4, 5, 6, 7],
+                                 [1, 2, 3, 4, 5, 6, 7, 8]], jnp.int32)
+        valid = jnp.asarray([[True] * 7 + [False], [True] * 8])
+        k, v = (jnp.asarray(np_rng.randn(2, 8, kv, hd), jnp.bfloat16)
+                for _ in range(2))
+        zeros = lambda *shape: jnp.zeros(shape, jnp.bfloat16)
+        kb, vb = (scatter_tokens(zeros(n_blocks, bs, kv, hd), table,
+                                 positions, x, valid) for x in (k, v))
+        want = pack_pair(kb, vb)
+        lead = () if layer is None else (n_layers,)
+        pool = PackedKVPair(
+            jnp.zeros((*lead, n_blocks, bs, kv, hd), jnp.uint8),
+            jnp.zeros((*lead, n_blocks, kv, 2 * bs * hd // F.GROUP),
+                      jnp.uint8))
+        out = scatter_tokens(pool, table, positions, (k, v), valid, layer)
+        for got, ref_leaf in zip(out, want):
+            got = np.asarray(got)
+            if layer is not None:
+                assert not np.delete(got, layer, axis=0).any()
+                got = got[layer]
+            np.testing.assert_array_equal(got, np.asarray(ref_leaf))
+        for got, ref_view in zip(gather_view(out, table, layer),
+                                 (gather_view(x, table)
+                                  for x in unpack_pair(want))):
+            np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                          np.asarray(ref_view, np.float32))
+        dec = unpack_pair(want)
+        for d, x in zip(dec, (kb, vb)):
+            np.testing.assert_array_equal(
+                np.asarray(d, np.float32),
+                np.asarray(F.nvfp4_cache_decode(*F.nvfp4_cache_encode(x)),
+                           np.float32))
+
 
 # --------------------------------------------------------------------------
 # allocator: quantized pool construction, atomic COW, overflow probe
@@ -207,14 +250,14 @@ def _qpool(n_blocks=8) -> KVPool:
 class TestQuantizedPool:
     def test_token_leaves_are_packed(self):
         pool = _qpool()
-        k, v = pool.caches[0]["l0"]["kv"]
-        for leaf in (k, v):
-            assert isinstance(leaf, PackedKV)
-            assert leaf.codes.dtype == jnp.uint8
-            assert leaf.scales.dtype == jnp.uint8
-            # (layers, n_blocks, block, kv_heads, hd/2) / (..., hd/16)
-            assert leaf.codes.shape == (1, 8, 4, 2, 8)
-            assert leaf.scales.shape == (1, 8, 4, 2, 1)
+        leaf = pool.caches[0]["l0"]["kv"]
+        assert isinstance(leaf, PackedKVPair)
+        assert leaf.codes.dtype == jnp.uint8
+        assert leaf.scales.dtype == jnp.uint8
+        # (layers, n_blocks, block, kv_heads, K's hd/2 | V's hd/2) and
+        # (layers, n_blocks, kv_heads, K's then V's block x hd/16)
+        assert leaf.codes.shape == (1, 8, 4, 2, 16)
+        assert leaf.scales.shape == (1, 8, 2, 2 * 4 * 1)
 
     def test_quantized_requires_paged(self):
         with pytest.raises(ValueError, match="paged"):
@@ -240,12 +283,11 @@ class TestQuantizedPool:
         pool.commit(1, 8)
         dst = pool.cow_block(1, src)
         assert dst != src
-        k, v = pool.caches[0]["l0"]["kv"]
-        for leaf in (k, v):
-            np.testing.assert_array_equal(np.asarray(leaf.codes[:, dst]),
-                                          np.asarray(leaf.codes[:, src]))
-            np.testing.assert_array_equal(np.asarray(leaf.scales[:, dst]),
-                                          np.asarray(leaf.scales[:, src]))
+        leaf = pool.caches[0]["l0"]["kv"]
+        np.testing.assert_array_equal(np.asarray(leaf.codes[:, dst]),
+                                      np.asarray(leaf.codes[:, src]))
+        np.testing.assert_array_equal(np.asarray(leaf.scales[:, dst]),
+                                      np.asarray(leaf.scales[:, src]))
 
     def test_overflow_probe(self, np_rng):
         """The debug-mode detector replays the scale chain host-side
@@ -299,20 +341,20 @@ class TestQuantKernelParity:
         lens = [5, 11, 16, 0]     # ragged; partial tables; row 3 inactive
         pos = jnp.asarray([max(n - sq, 0) for n in lens], jnp.int32)
         table = _mk_table(np_rng, lens, len(lens))
-        kp = _packed(_fill_pool(np_rng, table, lens, kv, hd))
-        vp = _packed(_fill_pool(np_rng, table, lens, kv, hd))
+        pool = pack_pair(_fill_pool(np_rng, table, lens, kv, hd),
+                         _fill_pool(np_rng, table, lens, kv, hd))
         q = jnp.asarray(np_rng.randn(len(lens), sq, h, hd) * 0.5, jnp.float32)
 
-        out = ops.paged_attention_q(q, kp.codes, kp.scales, vp.codes,
-                                    vp.scales, table, pos, window=window)
-        want = ref.paged_attention_q_ref(q, kp.codes, kp.scales, vp.codes,
-                                         vp.scales, table, pos, window=window)
+        out = ops.paged_attention_q(q, pool.codes, pool.scales, table, pos,
+                                    window=window)
+        want = ref.paged_attention_q_ref(q, pool.codes, pool.scales, table,
+                                         pos, window=window)
         np.testing.assert_allclose(np.asarray(out), np.asarray(want),
                                    atol=ATOL, rtol=RTOL)
-        # inline composition: gather_view dequantizes PackedKV to bf16 —
-        # literally today's quantized gather serving path
-        inline = decode_sdpa(q, gather_view(kp, table), gather_view(vp, table),
-                             pos, window=window)
+        # inline composition: gather_view dequantizes the PackedKVPair to
+        # bf16 K and V — literally today's quantized gather serving path
+        inline = decode_sdpa(q, *gather_view(pool, table), pos,
+                             window=window)
         np.testing.assert_array_equal(np.asarray(want), np.asarray(inline))
         assert float(jnp.abs(out[3]).max()) == 0.0   # inactive row
         assert float(jnp.abs(want[3]).max()) == 0.0
@@ -397,10 +439,10 @@ class TestEngineKVQuant:
             n_slots=2, max_len=64, paged=True, kv_quant=True,
             prequant=False, scheme="bf16"))
         assert eng.pool.quantized
-        k, v = eng.pool.caches[0]["l0"]["kv"]
-        assert isinstance(k, PackedKV) and isinstance(v, PackedKV)
-        packed = k.codes.size + k.scales.size
-        bf16 = (k.codes.size * 2) * 2       # same elements at 2 B each
+        kv = eng.pool.caches[0]["l0"]["kv"]
+        assert isinstance(kv, PackedKVPair)
+        packed = kv.codes.size + kv.scales.size
+        bf16 = kv.codes.size * 2 * 2        # K+V elements at 2 B each
         assert packed / bf16 == 0.28125
 
     def test_prefix_cache_hot_equals_cold(self, base_key, np_rng):

@@ -27,11 +27,12 @@ import numpy as np
 import pytest
 
 from repro.configs import registry
+from repro.core import formats as F
 from repro.kernels import ops, ref
 from repro.models import lm
 from repro.models.attention import decode_sdpa
 from repro.serve.engine import EngineConfig, Request, ServeEngine
-from repro.serve.kv_pool import gather_view
+from repro.serve.kv_pool import gather_view, pack_pair
 
 ATOL, RTOL = 5e-6, 1e-5
 
@@ -161,6 +162,67 @@ class TestMLAKernel:
         np.testing.assert_allclose(np.asarray(out), np.asarray(want),
                                    atol=ATOL, rtol=RTOL)
         assert float(jnp.abs(out[2]).max()) == 0.0  # inactive row
+
+
+class TestLayerIndexedKernels:
+    """The serving step hands every paged kernel the STACKED (L, P, ...)
+    pool and a layer index, never a per-layer slice. Reading layer l of the
+    stack in place must equal, bit for bit, the call on the sliced pool[l]
+    (each layer of the stack holds different values and garbage, so a
+    wrong layer cannot pass)."""
+
+    L = 3
+
+    def _stack(self, np_rng, table, lens, *feat, packed=False):
+        pools = jnp.stack([_fill_pool(np_rng, table, lens, *feat)
+                           for _ in range(self.L)])
+        if packed:
+            return list(F.nvfp4_cache_encode(pools))
+        return [pools]
+
+    @pytest.mark.parametrize("layer", [0, 2])
+    @pytest.mark.parametrize("sq,window", [(1, None), (3, None), (3, 6)])
+    @pytest.mark.parametrize("packed", [False, True], ids=["bf16", "nvfp4"])
+    def test_gqa_layer_equals_slice(self, packed, sq, window, layer, np_rng):
+        kv, rep, hd = 2, 2, 32
+        lens = [5, 11, 16, 0]
+        pos = jnp.asarray([max(n - sq, 0) for n in lens], jnp.int32)
+        table = _mk_table(np_rng, lens, len(lens))
+        k, v = (self._stack(np_rng, table, lens, kv, hd)[0]
+                for _ in range(2))
+        # the packed pool holds K and V together (kv_pool.PackedKVPair)
+        pool = list(pack_pair(k, v)) if packed else [k, v]
+        q = jnp.asarray(np_rng.randn(len(lens), sq, kv * rep, hd) * 0.5,
+                        jnp.float32)
+        kernel = ops.paged_attention_q if packed else ops.paged_attention
+        out = kernel(q, *pool, table, pos, jnp.int32(layer), window=window)
+        want = kernel(q, *[a[layer] for a in pool], table, pos,
+                      window=window)
+        np.testing.assert_array_equal(np.asarray(out), np.asarray(want))
+        assert float(jnp.abs(out[:3]).max()) > 0.0
+
+    @pytest.mark.parametrize("layer", [0, 2])
+    @pytest.mark.parametrize("sq", [1, 3])
+    @pytest.mark.parametrize("packed", [False, True], ids=["bf16", "nvfp4"])
+    def test_mla_layer_equals_slice(self, packed, sq, layer, np_rng):
+        h, lora, rope, qk_dim = 3, 32, 16, 48
+        lens = [6, 14, 0]
+        pos = jnp.asarray([max(n - sq, 0) for n in lens], jnp.int32)
+        table = _mk_table(np_rng, lens, len(lens))
+        cc = self._stack(np_rng, table, lens, lora, packed=packed)
+        kc = self._stack(np_rng, table, lens, rope, packed=packed)
+        qa = jnp.asarray(np_rng.randn(len(lens), sq, h, lora) * 0.5,
+                         jnp.float32)
+        qr = jnp.asarray(np_rng.randn(len(lens), sq, h, rope) * 0.5,
+                         jnp.float32)
+        kernel = (ops.paged_mla_attention_q if packed
+                  else ops.paged_mla_attention)
+        out = kernel(qa, qr, *cc, *kc, table, pos, jnp.int32(layer),
+                     qk_dim=qk_dim)
+        want = kernel(qa, qr, *[a[layer] for a in (*cc, *kc)], table, pos,
+                      qk_dim=qk_dim)
+        np.testing.assert_array_equal(np.asarray(out), np.asarray(want))
+        assert float(jnp.abs(out[:2]).max()) > 0.0
 
 
 # --------------------------------------------------------------------------

@@ -38,8 +38,10 @@ from repro.configs import registry
 from repro.configs.base import ArchConfig
 from repro.models import lm
 from repro.serve.engine import EngineConfig, Request, ServeEngine, Unservable
-from repro.serve.kv_pool import KVPool, OutOfBlocks, PackedKV
+from repro.serve.kv_pool import KVPool, OutOfBlocks, PackedKV, PackedKVPair
 from repro.serve.prefix_cache import PrefixCache
+
+PACKED = (PackedKV, PackedKVPair)   # the quantized pool's token-kind leaves
 
 pytestmark = pytest.mark.serve
 
@@ -412,7 +414,8 @@ def test_spill_hot_stream_parity_and_zero_prefix_prefill(kv_quant):
 
 
 def test_kv_quant_spill_payload_stays_packed():
-    """The host tier stores kv_quant blocks as PackedKV uint8 bytes —
+    """The host tier stores kv_quant blocks as packed uint8 bytes
+    (`PackedKVPair` for attention layers, `PackedKV` for latent ones) —
     never dequantized — so spill-hot is byte-exact by construction and the
     snapshot costs the packed footprint, not the bf16 one."""
     cfg = _serve_cfg()
@@ -430,12 +433,12 @@ def test_kv_quant_spill_payload_stays_packed():
         for c in n.children.values():
             if c.host is not None:
                 packed.extend(jax.tree_util.tree_leaves(
-                    c.host, is_leaf=lambda x: isinstance(x, PackedKV)))
+                    c.host, is_leaf=lambda x: isinstance(x, PACKED)))
             walk(c)
 
     walk(eng.cache.root)
     assert packed
-    assert all(isinstance(p, PackedKV) for p in packed)
+    assert all(isinstance(p, PACKED) for p in packed)
     leaves = [a for p in packed for a in jax.tree_util.tree_leaves(p)]
     assert all(a.dtype.itemsize == 1 for a in leaves)  # packed bytes only
 

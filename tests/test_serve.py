@@ -340,6 +340,66 @@ def test_pool_oob_sentinel_drops_writes():
     assert np.asarray(pool, np.float32)[0].sum() == 0  # block 0 untouched
 
 
+@pytest.mark.parametrize("arch", ["yi_9b", "deepseek_v3_671b",
+                                  "recurrentgemma_9b"])
+@pytest.mark.parametrize("paged_kernel", [False, True],
+                         ids=["gather", "kernel"])
+def test_donated_step_keeps_unwritten_pool_bits(arch, paged_kernel):
+    """The engine step carries the donated pool through its layer scan and
+    updates it in place. Every pool position the step does not write (other
+    blocks, an inactive row's blocks, offsets past the chunk), and every
+    recurrent state row it does not run, comes back bit for bit; the rest
+    equals the same step without donation."""
+    from repro.serve import decode as D
+    from repro.serve.kv_pool import STATE_KINDS, TOKEN_KINDS
+    cfg = _cfg(arch)
+    n_slots, max_len, bs, sq = 3, 32, 4, 3
+    pool = KVPool(cfg, n_slots, max_len, block_size=bs, n_blocks=12)
+    rng = np.random.RandomState(3)
+    # garbage everywhere, so an untouched position is told from a zeroed one
+    caches = jax.tree.map(
+        lambda a: jnp.asarray(rng.randn(*a.shape), a.dtype), pool.caches)
+    table = np.full((n_slots, pool.max_blocks), pool.sentinel, np.int32)
+    table[0, :3], table[1, :2], table[2, :1] = [7, 2, 9], [4, 11], [0]
+    pos = np.array([5, 1, 0], np.int32)
+    active = np.array([True, False, True])
+    tokens = jnp.asarray(rng.randint(0, cfg.vocab, (n_slots, sq)), jnp.int32)
+    args = (jnp.asarray(np.stack([table, table], 1)), tokens,
+            jnp.asarray(pos), jnp.asarray(active))
+    step = D.make_paged_serve_step(cfg, "bf16", paged_kernel=paged_kernel)
+    params = _params(cfg)
+    before = jax.tree.map(np.asarray, caches)
+    want_logits, want = jax.jit(step)(params, caches, *args)
+    logits, got = jax.jit(step, donate_argnums=(1,))(
+        params, jax.tree.map(jnp.copy, caches), *args)
+    np.testing.assert_array_equal(np.asarray(logits), np.asarray(want_logits))
+    jax.tree.map(lambda g, w: np.testing.assert_array_equal(
+        np.asarray(g), np.asarray(w)), got, want)
+
+    written = np.zeros((pool.n_blocks, bs), bool)
+    for b in np.flatnonzero(active):
+        for p in range(pos[b], pos[b] + sq):
+            written[table[b, p // bs], p % bs] = True
+    n_checked = 0
+    for stage_got, stage_before in zip(got, before):
+        for lk, kinds in stage_got.items():
+            for kind, leaf in kinds.items():
+                for g, b in zip(jax.tree.leaves(leaf),
+                                jax.tree.leaves(stage_before[lk][kind])):
+                    g = np.asarray(g)
+                    if kind in TOKEN_KINDS:     # (L, P, BS, ...)
+                        np.testing.assert_array_equal(g[:, ~written],
+                                                      b[:, ~written])
+                        assert all((g[i][written] != b[i][written]).any()
+                                   for i in range(len(g)))
+                    else:                       # (L, n_slots, ...)
+                        assert kind in STATE_KINDS
+                        np.testing.assert_array_equal(g[:, ~active],
+                                                      b[:, ~active])
+                    n_checked += 1
+    assert n_checked >= 2
+
+
 # --------------------------------------------------------------------------
 # satellite: ragged prompts through the legacy greedy loop
 # --------------------------------------------------------------------------
