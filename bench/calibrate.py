@@ -13,7 +13,9 @@ the served tokens; with --control it also reads the gap of the tokens that
 the reference computed at that lower precision puts first on the same
 prompts and tokens. `train-check` reads the program's first steps against
 the reference; with --control, the reference at that precision against the
-reference; with --fault, the program with that fault planted.
+reference; with --fault, the program with that fault planted. `knee` and
+`serve-check` build the cell's server as a benchmark run does
+(lib/serve.py `Server`), on its mesh where the configuration names one.
 """
 
 from __future__ import annotations
@@ -57,7 +59,7 @@ def knee(cell, args) -> None:
         cell.traffic, args.seconds, args.seed, m["vocab"])["prime"])
     import jax
     emit(phase="setup", seconds=time.monotonic() - PROCESS_START,
-         memory=jax.devices()[0].memory_stats())
+         memory=[d.memory_stats() for d in jax.devices()[:cell.chips]])
     for rate in [float(r) for r in args.rates.split(",")]:
         tf = dict(cell.traffic, arrival={"process": "poisson",
                                          "rate_rps": rate})
@@ -217,6 +219,7 @@ def main() -> None:
     cell = C.load(ROOT, args.workload)
     if args.config:     # read another configuration under this cell's mix
         cell.config = json.loads(Path(args.config).read_text())
+    cell.mesh_shape()   # a serving mesh has to take the cell's chips
     emit(device=jax.devices()[0].device_kind, count=len(jax.devices()),
          memory=(jax.devices()[0].memory_stats() or {}).get("bytes_limit"))
     {"knee": knee, "serve-check": serve_check, "train-mem": train_mem,

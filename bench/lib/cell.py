@@ -32,6 +32,23 @@ class Cell:
     end_to_end: list
     per_layer: list
 
+    def mesh_shape(self) -> tuple[int, int] | None:
+        """(data, model) of the serving mesh that the configuration's
+        `engine.mesh` names, None where it names none. ValueError unless
+        data x model is the cell's chips."""
+        mesh = self.config.get("engine", {}).get("mesh")
+        if mesh is None:
+            return None
+        shape = (mesh.get("data"), mesh.get("model"))
+        if (set(mesh) != {"data", "model"}
+                or not all(isinstance(n, int) and n > 0 for n in shape)
+                or shape[0] * shape[1] != self.chips):
+            raise ValueError(
+                f"cell {self.name}: engine.mesh {mesh} must be "
+                f'{{"data": D, "model": M}} with D x M = chips '
+                f"({self.chips})")
+        return shape
+
     def reader(self, metric: str):
         """The `read(run)` function of bench/metrics/<metric>.py."""
         path = BENCH_DIR / "metrics" / f"{metric}.py"

@@ -10,11 +10,22 @@ BF16 = 2
 F32 = 4
 
 
+# d x d_ff matrices of each MLP kind the program has (models/blocks.py):
+# SwiGLU's gate, up and down; the squared-ReLU and GELU MLPs' up and down
+MLP_MATRICES = {"swiglu": 3, "relu2": 2, "gelu": 2}
+
+
 def layer_params(m: dict) -> int:
-    """Weights of one GQA + SwiGLU layer that go through a GEMM."""
+    """Weights of one GQA layer that go through a GEMM: the four attention
+    projections and the MLP's matrices. A model with no `mlp` key has the
+    program's default, SwiGLU; a kind not in MLP_MATRICES is refused."""
     d, hd = m["d_model"], m["head_dim"]
     q, kv = m["n_heads"] * hd, m["n_kv_heads"] * hd
-    return d * q + 2 * d * kv + q * d + 3 * d * m["d_ff"]
+    kind = m.get("mlp", "swiglu")
+    if kind not in MLP_MATRICES:
+        raise ValueError(f"no GEMM count for mlp {kind!r}; "
+                         f"have {sorted(MLP_MATRICES)}")
+    return d * q + 2 * d * kv + q * d + MLP_MATRICES[kind] * d * m["d_ff"]
 
 
 def gemm_params(m: dict) -> int:

@@ -1,4 +1,5 @@
-"""Device busy time and the breakdown of a reduced trace."""
+"""A run's device record; device busy time and the breakdown of a
+reduced trace."""
 
 from __future__ import annotations
 
@@ -10,6 +11,16 @@ from bench.lib.trace import SPAN_PREFIX
 TOP = 10
 # ops that contain other ops of the same line (a scan's loop)
 CONTAINERS = ("while", "conditional", "call")
+
+
+def device(chips) -> dict:
+    """Platform, kind and count of the cell's chips as JAX reports them,
+    the peak bytes in use on each, and that of the fullest."""
+    peaks = [(d.memory_stats() or {}).get("peak_bytes_in_use", 0)
+             for d in chips]
+    return {"platform": chips[0].platform, "kind": chips[0].device_kind,
+            "count": len(chips), "memory_peak_bytes": max(peaks),
+            "memory_peak_bytes_per_chip": peaks}
 
 
 def short_name(op: str) -> str:

@@ -1,10 +1,13 @@
 """Serving cells: the program's ServeEngine behind EngineBridge and
 CompletionFrontend on localhost, loaded by a child process over HTTP/SSE.
 
-This process holds the chip. Set-up makes the weights on the device from
-the seed (one jitted call, packed layer by layer), builds the engine and
-runs one short request through it, so that every compiled step the traffic
-uses exists before the window. The load generator (bench/lib/loadgen.py)
+This process holds the chip(s). Set-up makes the weights on the device
+from the seed (one jitted call, packed layer by layer), builds the engine
+and runs one short request through it, so that every compiled step the
+traffic uses exists before the window. Where the configuration's
+`engine` names a mesh, `"mesh": {"data": D, "model": M}`, the engine is
+the program's sharded one on `make_serve_mesh(D, M)`, and the weights are
+made on their shards. The load generator (bench/lib/loadgen.py)
 is a child process that never imports JAX; it sends the cell's schedule
 and takes every timestamp on the client side.
 
@@ -33,7 +36,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from bench.lib import cell as C
-from bench.lib import check_serve, gen, stats
+from bench.lib import check_serve, gen, report, stats
 from bench.lib import trace as T
 
 LOADGEN = Path(__file__).with_name("loadgen.py")
@@ -53,13 +56,19 @@ def arch_config(m: dict):
     return ArchConfig(**{k: v for k, v in m.items() if k in fields})
 
 
-def serving_params(m: dict, seed: int, scheme: str, weights):
+def serving_params(m: dict, seed: int, scheme: str, weights, mesh=None):
     """Serving weights made on the device in one jitted call by the
     configuration's weight module, each layer packed by the program's
     `prequantize` (a no-op for schemes that keep weights in floating
     point, whose GEMM weights, embedding and head are then served in
     weights.SERVED_DTYPE). The tree must be what
-    `prequantize_specs(eval_shape(lm.init))` describes."""
+    `prequantize_specs(eval_shape(lm.init))` describes.
+
+    With a mesh, each leaf is made on the shards that the program's
+    `serve_param_shardings` gives it, so no device holds more than its
+    share; the values are the same as without one (threefry is
+    partitionable: each shard draws its own part of the same numbers)."""
+    from repro.dist import sharding as SH
     from repro.models import lm
     from repro.serve.prequant import prequantize, prequantize_specs
 
@@ -82,11 +91,19 @@ def serving_params(m: dict, seed: int, scheme: str, weights):
         packed = prequantize(one, cfg, scheme)["stages"][0]["l0"]
         return cast(jax.tree.map(lambda x: x[0], packed))
 
-    params = weights.make_params(seed, m, pack, embed_dtype=dtype)
-    want = cast(prequantize_specs(
-        jax.eval_shape(lambda: lm.init(cfg, jax.random.PRNGKey(0))), cfg,
-        scheme))
-    if not same_tree(params, want):
+    # the tree is traced after the weights are made, and before them only
+    # where a mesh needs their shardings: traced first, it raised the chat
+    # cell's peak device memory by 5.7 MB on a v5e
+    def specs():
+        return cast(prequantize_specs(
+            jax.eval_shape(lambda: lm.init(cfg, jax.random.PRNGKey(0))),
+            cfg, scheme))
+
+    params = weights.make_params(
+        seed, m, pack, embed_dtype=dtype,
+        out_shardings=None if mesh is None else SH.serve_param_shardings(
+            specs(), mesh))
+    if not same_tree(params, specs()):
         raise AssertionError("serving weights do not match the program's "
                              "prequantized tree")
     return params
@@ -133,6 +150,7 @@ class Server:
     """Engine + bridge + frontend for one configuration and seed."""
 
     def __init__(self, config: dict, seed: int, prime=()):
+        from repro.launch.mesh import make_serve_mesh
         from repro.obs.instrumentation import Instrumentation
         from repro.obs.metrics import MetricsRegistry
         from repro.serve.engine import EngineConfig, Request, ServeEngine
@@ -142,15 +160,17 @@ class Server:
         m, e = config["model"], config["engine"]
         self.model = m
         self.cfg = arch_config(m)
+        mesh = (make_serve_mesh(e["mesh"]["data"], e["mesh"]["model"])
+                if "mesh" in e else None)
         self.params = serving_params(m, seed, e["scheme"],
-                                     C.module(config["weights"]))
+                                     C.module(config["weights"]), mesh)
         self.obs = Instrumentation(registry=MetricsRegistry())
         self.engine = ServeEngine(self.cfg, self.params, EngineConfig(
             n_slots=e["n_slots"], max_len=e["max_len"],
             block_size=e["block_size"], n_blocks=e["n_blocks"],
             prefill_chunk=e["prefill_chunk"], scheme=e["scheme"],
-            prequant=False, prefix_cache=e["prefix_cache"], obs=self.obs,
-            clock=time.monotonic))
+            prequant=False, prefix_cache=e["prefix_cache"], mesh=mesh,
+            obs=self.obs, clock=time.monotonic))
         self.rec = Recorder()
         self.rec.attach(self.engine)
         # warm-up: one prompt of a chunk plus one token, two new tokens,
@@ -322,8 +342,7 @@ def run(cell, seed: int, seconds: float, traced: bool, out_dir: Path,
     server.close()
     del server
     gc.collect()
-    dev = jax.devices()[0]
-    peak = (dev.memory_stats() or {}).get("peak_bytes_in_use", 0)
+    device = report.device(jax.devices()[:cell.chips])
     nums = client_numbers(win)
     sys.stderr.write(f"generator lateness (s): {json.dumps(nums['lateness_s'])}\n")
     (out_dir / "lateness.json").write_text(json.dumps(nums["lateness_s"]))
@@ -332,7 +351,8 @@ def run(cell, seed: int, seconds: float, traced: bool, out_dir: Path,
                                 sample, tf["check"], nums["failed"],
                                 check_serve.model_kw(cell.config))
     if traced:
-        ctx = {"model": m, "window": win, "peaks": cell.peaks}
+        ctx = {"model": m, "window": win, "peaks": cell.peaks,
+               "chips": cell.chips}
         metrics = {}
         for spec in cell.per_layer:
             v = cell.reader(spec["name"])(ctx)
@@ -344,7 +364,5 @@ def run(cell, seed: int, seconds: float, traced: bool, out_dir: Path,
         metrics = {s["name"]: {"value": e2e[s["name"]], "unit": s["unit"]}
                    for s in cell.end_to_end if e2e.get(s["name"]) is not None}
     return {"correct": check["correct"], "attempted": nums["attempted"],
-            "failed": nums["failed"], "metrics": metrics,
-            "device": {"platform": dev.platform, "kind": dev.device_kind,
-                       "count": cell.chips, "memory_peak_bytes": peak},
+            "failed": nums["failed"], "metrics": metrics, "device": device,
             "trace": win["trace"], "check": check["numbers"]}

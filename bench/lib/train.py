@@ -23,7 +23,7 @@ import jax
 import jax.numpy as jnp
 
 from bench.lib import cell as C
-from bench.lib import check_train
+from bench.lib import check_train, report
 from bench.lib import trace as T
 from bench.lib.serve import arch_config, same_tree
 
@@ -149,8 +149,7 @@ def run(cell, seed: int, seconds: float, traced: bool, out_dir: Path,
     setup_s = time.monotonic() - process_start
     n, window_s, red = window(tr, seconds, T.TRACE_S if traced else 0.0,
                               out_dir / "trace")
-    dev = jax.devices()[0]
-    peak = (dev.memory_stats() or {}).get("peak_bytes_in_use", 0)
+    device = report.device(jax.devices()[:cell.chips])
     batches = tr.batches(CHECK_STEPS)
     del tr
     gc.collect()
@@ -159,7 +158,7 @@ def run(cell, seed: int, seconds: float, traced: bool, out_dir: Path,
     tok_s = n * t["batch"] * t["seq"] / window_s
     if traced:
         ctx = {"model": m, "trainer": t, "train_tok_s": tok_s,
-               "trace": red, "peaks": cell.peaks}
+               "trace": red, "peaks": cell.peaks, "chips": cell.chips}
         metrics = {}
         for spec in cell.per_layer:
             v = cell.reader(spec["name"])(ctx)
@@ -170,8 +169,5 @@ def run(cell, seed: int, seconds: float, traced: bool, out_dir: Path,
         metrics = {s["name"]: {"value": e2e[s["name"]], "unit": s["unit"]}
                    for s in cell.end_to_end}
     return {"correct": check["correct"], "attempted": n, "failed": 0,
-            "metrics": metrics,
-            "device": {"platform": dev.platform, "kind": dev.device_kind,
-                       "count": cell.chips, "memory_peak_bytes": peak},
-            "trace": red, "check": check["numbers"],
-            "detail": check["detail"]}
+            "metrics": metrics, "device": device, "trace": red,
+            "check": check["numbers"], "detail": check["detail"]}

@@ -63,11 +63,14 @@ def embed_head(seed: int, m: dict):
 
 
 def make_params(seed: int, m: dict, pack=None,
-                embed_dtype: str = "float32") -> dict:
+                embed_dtype: str = "float32", out_shardings=None) -> dict:
     """The whole model in the program's `lm.init` tree, made on the device
     in one jitted call. `pack(layer) -> layer` (the program's packer)
     turns each layer into its served type before the next is made; the
-    embedding and head are stored in `embed_dtype`."""
+    embedding and head are stored in `embed_dtype`. `out_shardings`, a
+    tree of shardings like the result's, makes each leaf on its shards
+    (the same values: threefry is partitionable); None makes the model on
+    the default device."""
     k = keys(seed)
 
     def build():
@@ -80,4 +83,6 @@ def make_params(seed: int, m: dict, pack=None,
                 "final_norm": {"g": jnp.ones((m["d_model"],), jnp.float32)},
                 "head": head}
 
-    return jax.jit(build)()
+    if out_shardings is None:
+        return jax.jit(build)()
+    return jax.jit(build, out_shardings=out_shardings)()

@@ -1,6 +1,7 @@
 """Whole serving step: model FLOPs of the tokens the engine computed in the
 window (prompt chunks and decode tokens of active rows, padding rows not
-counted; bench/lib/flops.py) over window x the chip's bf16 peak."""
+counted; bench/lib/flops.py) over window x the cell's chips x one chip's
+bf16 peak."""
 
 from bench.lib import flops
 
@@ -11,4 +12,5 @@ def read(ctx):
                 for _, size, pos, act in w["forwards"])
     if not total:
         return None
-    return 100.0 * total / (w["seconds"] * ctx["peaks"]["flops_bf16"])
+    return 100.0 * total / (w["seconds"] * ctx["chips"]
+                            * ctx["peaks"]["flops_bf16"])

@@ -6,8 +6,10 @@
 Run from the root of a checkout. BENCHMARK.json names the cell; its
 configuration, traffic mix and per-layer readers are found by name under
 bench/ (bench/README.md). Exits non-zero, printing no result, when the
-program is not beside the benchmark, when JAX sees no TPU or fewer chips
-than the cell asks for, or when the device is not in bench/peaks.json.
+program is not beside the benchmark, when a serving mesh does not take
+the cell's chips, when JAX sees no TPU or fewer chips than the cell asks
+for (with a mesh: other than the cell's chips), or when the device is not
+in bench/peaks.json.
 
 The last line of stdout is one JSON object: correct, attempted, failed,
 metrics (the cell's end-to-end metrics, or with --trace 1 its per-layer
@@ -52,11 +54,13 @@ def configure_cache(jax) -> None:
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
 
 
-def device_gate(jax, chips: int):
+def device_gate(jax, chips: int, mesh: bool):
+    """The devices JAX sees: a TPU, at least `chips` of them, and with a
+    mesh exactly `chips` (the mesh takes every device)."""
     devices = jax.devices()
     if devices[0].platform != "tpu":
         fail(3, f"no TPU (JAX sees {devices[0].platform})")
-    if len(devices) < chips:
+    if len(devices) < chips or (mesh and len(devices) != chips):
         fail(3, f"the cell needs {chips} TPU chips, JAX sees {len(devices)}")
     return devices
 
@@ -76,9 +80,13 @@ def main() -> None:
     from bench.lib import report
 
     cell = C.load(ROOT, args.workload)
+    try:
+        mesh = cell.mesh_shape()
+    except ValueError as e:
+        fail(5, str(e))
     import jax
     configure_cache(jax)
-    devices = device_gate(jax, cell.chips)
+    devices = device_gate(jax, cell.chips, mesh is not None)
     peaks = json.loads((ROOT / "bench" / "peaks.json").read_text())
     kind = devices[0].device_kind
     if kind not in peaks["devices"]:

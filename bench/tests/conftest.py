@@ -1,10 +1,19 @@
-"""The harness's own tests run on the CPU: python -m pytest bench/tests."""
+"""The harness's own tests run on the CPU: python -m pytest bench/tests.
+
+Four CPU devices stand for a four-chip host, for the tests of a serving
+mesh; every other test runs on the first. JAX fixes the device count when
+it starts, so this is set before any test module imports it."""
 
 import os
 import sys
 from pathlib import Path
 
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
+if "--xla_force_host_platform_device_count" not in os.environ.get(
+        "XLA_FLAGS", ""):
+    os.environ["XLA_FLAGS"] = (
+        os.environ.get("XLA_FLAGS", "")
+        + " --xla_force_host_platform_device_count=4").strip()
 ROOT = Path(__file__).resolve().parents[2]
 for p in (str(ROOT), str(ROOT / "src")):
     if p not in sys.path:

@@ -4,6 +4,8 @@ Each test skips only the harness's look for a chip: it drives the rest of
 a run (set-up, the window over HTTP or the train loop, the reference
 comparison) on a CPU-sized cut of the cell, with one fault planted in the
 program. A sound run of the same cut is checked to read `correct` true.
+The serving tests run on one device and on a (data=2, model=2) mesh of
+four CPU devices, the program's sharded engine.
 """
 
 import pytest
@@ -12,11 +14,29 @@ from bench.tests import tiny
 
 SERVE = "granite-8b-bf16.chat"
 TRAIN = "yi-9b-train.seq2k"
+MESHES = pytest.mark.parametrize("mesh", [None, {"data": 2, "model": 2}],
+                                 ids=["one_device", "mesh_2x2"])
 
 
-def test_serve_sound(tmp_path):
-    res = tiny.run(tiny.cell(SERVE), 2**31 + 3, tmp_path)
+@MESHES
+def test_serve_sound(tmp_path, monkeypatch, mesh):
+    """Correct, on the sharded engine where the configuration names a
+    mesh, with a memory reading for each of the cell's chips."""
+    from repro.serve.engine import ServeEngine
+    init, shards = ServeEngine.__init__, []
+
+    def spy(self, *a, **k):
+        init(self, *a, **k)
+        shards.append(None if self.mesh is None else self.data_shards)
+
+    monkeypatch.setattr(ServeEngine, "__init__", spy)
+    c = tiny.cell(SERVE, mesh=mesh)
+    res = tiny.run(c, 2**31 + 3, tmp_path)
     assert res["correct"], res["check"]
+    assert shards == [None if mesh is None else mesh["data"]]
+    per_chip = res["device"]["memory_peak_bytes_per_chip"]
+    assert len(per_chip) == res["device"]["count"] == c.chips
+    assert res["device"]["memory_peak_bytes"] == max(per_chip)
 
 
 def test_serve_closed_loop_sound(tmp_path):
@@ -34,7 +54,8 @@ def test_serve_closed_loop_sound(tmp_path):
     assert res["correct"] and res["attempted"] >= 3, res
 
 
-def test_serve_token_altered(tmp_path, monkeypatch):
+@MESHES
+def test_serve_token_altered(tmp_path, monkeypatch, mesh):
     """Every sampled token is replaced by its neighbour id."""
     from repro.serve.engine import ServeEngine
     sample = ServeEngine._sample
@@ -43,7 +64,7 @@ def test_serve_token_altered(tmp_path, monkeypatch):
         return (sample(self, logits) + 1) % logits.shape[-1]
 
     monkeypatch.setattr(ServeEngine, "_sample", altered)
-    res = tiny.run(tiny.cell(SERVE), 2**31 + 3, tmp_path)
+    res = tiny.run(tiny.cell(SERVE, mesh=mesh), 2**31 + 3, tmp_path)
     assert not res["correct"], res["check"]
 
 

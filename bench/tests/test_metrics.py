@@ -27,7 +27,7 @@ def _serve_ctx(trace=None):
              "prefill_skipped_tokens": 50}
     fw = [(1.0, 16, np.array([0, 0]), np.array([True, False])),
           (2.0, 1, np.array([16, 3]), np.array([True, True]))]
-    return {"model": M, "peaks": PEAKS,
+    return {"model": M, "peaks": PEAKS, "chips": 1,
             "window": {"stats": stats, "forwards": fw, "seconds": 4.0,
                        "traces": [_Trace(w) for w in (0.1, 0.2, 0.3, 0.4)],
                        "trace": trace}}
@@ -58,6 +58,30 @@ def test_serve_mfu_counts_active_rows():
         pytest.approx(100 * want / (4.0 * 1e12))
 
 
+def test_serve_mfu_over_the_cells_chips():
+    one = _reader("serve_mfu")(_serve_ctx())
+    assert _reader("serve_mfu")(dict(_serve_ctx(), chips=4)) == \
+        pytest.approx(one / 4)
+
+
+class _Device:
+    platform, device_kind = "tpu", "TPU v5 lite"
+
+    def __init__(self, peak):
+        self.peak = peak
+
+    def memory_stats(self):
+        return {"peak_bytes_in_use": self.peak}
+
+
+@pytest.mark.parametrize("peaks", [[5], [5, 9, 7, 6]])
+def test_device_record_reads_every_chip(peaks):
+    d = report.device([_Device(p) for p in peaks])
+    assert d["count"] == len(peaks)
+    assert d["memory_peak_bytes_per_chip"] == peaks
+    assert d["memory_peak_bytes"] == max(peaks)
+
+
 def test_trace_readers():
     ctx = _serve_ctx(_trace())
     assert _reader("device_idle_share.serve")(ctx) == \
@@ -68,6 +92,23 @@ def test_trace_readers():
                 sum(w[1] for w in works) / 1e9)
     assert _reader("paged_attn_roofline")(ctx) == \
         pytest.approx(100 * least / 0.1)
+
+
+def test_roofline_finds_the_kernel_on_a_mesh():
+    """On a mesh XLA names the kernel's op after the shard_map it was
+    inlined from; its JAX name stack still ends in the kernel's jit and
+    its pallas_call. Any other op under that jit is not the kernel."""
+    one = _reader("paged_attn_roofline")(_serve_ctx(_trace()))
+    tr = _trace()
+    op = "%shard_map.217 = f32[8,1,32,128] custom-call()"
+    tr["device_ops"][1][0] = op
+    stack = ("jit(paged_serve_step)/shard_map/while/body/closed_call/"
+             "jit(paged_attention)/shard_map/")
+    tr["op_scopes"] = {op: stack + "pallas_call"}
+    assert _reader("paged_attn_roofline")(_serve_ctx(tr)) == \
+        pytest.approx(one)
+    tr["op_scopes"] = {op: stack + "reshape"}
+    assert _reader("paged_attn_roofline")(_serve_ctx(tr)) is None
 
 
 def test_readers_find_nothing():
@@ -94,7 +135,9 @@ def test_breakdown_labels_gaps_by_innermost_span():
 def test_train_mfu():
     yi = tiny.cell("yi-9b-train.seq2k")
     ctx = {"model": M, "trainer": {"seq": 128}, "train_tok_s": 1000.0,
-           "peaks": PEAKS, "trace": None}
+           "peaks": PEAKS, "trace": None, "chips": 1}
     assert yi.reader("train_mfu")(ctx) == pytest.approx(
         100 * 1000.0 * flops.train_token_flops(M, 128) / 1e12)
+    assert yi.reader("train_mfu")(dict(ctx, chips=4)) == pytest.approx(
+        100 * 1000.0 * flops.train_token_flops(M, 128) / 4e12)
     assert yi.reader("device_idle_share.train")(ctx) is None

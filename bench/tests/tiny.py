@@ -1,5 +1,7 @@
 """Cells cut to CPU size for the harness's tests: the real cell's files,
-with the model's sizes, the engine's slots and the traffic shrunk."""
+with the model's sizes, the engine's slots and the traffic shrunk, and
+for a serving cell optionally a (data, model) mesh over as many of the
+CPU devices (conftest.py)."""
 
 import copy
 import time
@@ -13,10 +15,13 @@ SMALL = dict(n_layers=4, d_model=128, n_heads=4, n_kv_heads=2, head_dim=32,
              d_ff=256, vocab=512)
 
 
-def cell(workload: str, **model) -> C.Cell:
+def cell(workload: str, mesh: dict | None = None, **model) -> C.Cell:
     c = C.load(ROOT, workload)
     cfg = copy.deepcopy(c.config)
     cfg["model"].update(SMALL, **model)
+    if mesh is not None:
+        cfg["engine"]["mesh"] = mesh
+        c.chips = mesh["data"] * mesh["model"]
     if "engine" in cfg:
         cfg["engine"].update(n_slots=4, max_len=256, n_blocks=64)
         c.traffic = dict(
