@@ -15,11 +15,14 @@ Backward orientation (inner dims):
 
 Activations are saved for the backward pass as *packed NVFP4* (uint8 nibble
 pairs + e4m3 scales = 4.5 bits/element) whenever the forward quantizes them —
-the memory-roofline lever on TPU.
+the memory-roofline lever on TPU. Where the quantized dX GEMM reads W's
+forward NVFP4 image (`Scheme.saves_w_image`), that image is saved in place
+of W (`_WImage`), so W is quantized once a step.
 """
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 
 import jax
@@ -252,6 +255,36 @@ def _unpack_qt(res) -> Q.QTensor:
                      scales8.astype(jnp.float32), gscale)
 
 
+@functools.partial(jax.tree_util.register_dataclass,
+                   data_fields=["vals", "gscale"], meta_fields=["dtype"])
+@dataclasses.dataclass(frozen=True)
+class _WImage:
+    """W's forward NVFP4 image, saved for the backward in place of W.
+
+    `vals` are the bf16 block values the forward GEMM reads
+    (`_block_values`, lossless), `gscale` the fp32 tensor scale, and
+    `dtype` W's own, which dW takes.
+    """
+
+    vals: jax.Array
+    gscale: jax.Array
+    dtype: jnp.dtype
+
+    @classmethod
+    def of(cls, qw: Q.QTensor, dtype) -> "_WImage":
+        with jax.named_scope(SC.GEMM):  # the values `_qmm` makes for W
+            return cls(_block_values(qw), qw.gscale, dtype)
+
+    @property
+    def shape(self) -> tuple:
+        return self.vals.shape
+
+    def dequant(self, dtype=jnp.float32) -> jax.Array:
+        """Bit-equal to `Q.dequant` of the image's QTensor, which multiplies
+        the exact fp4 * e4m3 products that `vals` holds by gscale."""
+        return (self.vals.astype(jnp.float32) * self.gscale).astype(dtype)
+
+
 # --------------------------------------------------------------------------
 # quantize-once weights (serving): the deterministic forward quantizers make
 # W's NVFP4 image a pure function of W, so inference packs it ONCE and decode
@@ -356,15 +389,17 @@ def _qlinear_fwd(x, w, seed, scheme):
     y = y.astype(x.dtype).reshape(*lead, -1)
 
     # Save activations as packed NVFP4 when the forward quantized them
-    # (paper Sec. 5: backward re-quantizes the SAVED quantized activations).
+    # (paper Sec. 5: backward re-quantizes the SAVED quantized activations),
+    # and W as its forward image where the dX GEMM reads that image.
     x_res = _pack_qt(qx) if qx is not None else x
-    return y, (x_res, w, seed)
+    w_res = _WImage.of(qw, w.dtype) if sch.saves_w_image else w
+    return y, (x_res, w_res, seed)
 
 
 def _qlinear_bwd(scheme, res, e):
     sch = S.get(scheme)
-    x_res, w, seed = res
-    n, k = w.shape
+    x_res, w_res, seed = res  # w_res: W's forward image where saves_w_image, else W
+    n, k = w_res.shape
     lead = e.shape[:-1]
     ef = e.reshape(-1, n)  # stays bf16 until after sharding hints
     m = ef.shape[0]
@@ -375,7 +410,7 @@ def _qlinear_bwd(scheme, res, e):
         xf = x_res.reshape(-1, k).astype(jnp.float32)
 
     if not sch.is_quantized or sch.bwd == "none":
-        dx = _mm(ef, w.T)                          # (M, K)
+        dx = _mm(ef, w_res.T)                      # (M, K)
         dw = _mm(ef.T, xf.T)                       # (N, K)
     else:
         m_pad = m + ((-m) % 128)
@@ -394,21 +429,21 @@ def _qlinear_bwd(scheme, res, e):
         # ---- dX = E @ W (inner dim N) ----
         if sch.quant_dx_e:
             if sch.dx_w_mode == "requant":
-                # de-quantize saved W, re-quantize along N with shared RHT
-                w_saved = (Q.dequant(_fwd_quant(w, sch.fwd_w))
-                           if sch.fwd_w != "none" else w.astype(jnp.float32))
+                # de-quantize W's saved image, re-quantize along N with shared RHT
+                w_saved = (w_res.dequant() if sch.saves_w_image
+                           else w_res.astype(jnp.float32))
                 dx = _bwd_gemm(ef, w_saved.T, sch.bwd, True, True,
                                use_rht=True, seed=seed, tag=1, specs=dx_specs)
             elif sch.dx_w_mode == "reuse":
                 assert sch.fwd_w == "square", "scale reuse needs square blocks"
-                wq = Q.dequant(_fwd_quant(w, "square"), jnp.bfloat16)
+                wq = w_res.dequant(jnp.bfloat16)
                 dx = _bwd_gemm(ef, wq.T, sch.bwd, True, False,
                                use_rht=False, seed=seed, tag=1, specs=dx_specs)
             else:  # "bf16"
-                dx = _bwd_gemm(ef, w.T.astype(jnp.float32), sch.bwd, True, False,
+                dx = _bwd_gemm(ef, w_res.T.astype(jnp.float32), sch.bwd, True, False,
                                use_rht=False, seed=seed, tag=1, specs=dx_specs)
         else:
-            dx = _mm(ef, w.T)
+            dx = _mm(ef, w_res.T)
 
         # ---- dW = E^T @ X (inner dim M) ----
         if sch.quant_dw_e or sch.quant_dw_x:
@@ -418,7 +453,7 @@ def _qlinear_bwd(scheme, res, e):
             dw = _mm(ef.T, xf.T)
 
     dx = dx.reshape(*lead, k).astype(e.dtype)
-    dw = dw.astype(w.dtype)
+    dw = dw.astype(w_res.dtype)
     return dx, dw, None
 
 

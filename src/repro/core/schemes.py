@@ -49,6 +49,15 @@ class Scheme:
     def rht_dw(self) -> bool:
         return self.quant_dw_e and self.quant_dw_x and self.bwd != "none"
 
+    @property
+    def saves_w_image(self) -> bool:
+        """The quantized dX GEMM reads W's forward NVFP4 image (re-quantized
+        along N, or reused as is): the forward saves that image for the
+        backward in place of W, so W is quantized once a step."""
+        return (self.fwd_w != "none" and self.bwd != "none" and self.quant_dx_e
+                and (self.dx_w_mode == "requant"
+                     or (self.dx_w_mode == "reuse" and self.fwd_w == "square")))
+
 
 _REGISTRY: dict[str, Scheme] = {}
 

@@ -6,8 +6,10 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.core import quant as Q
 from repro.core import schemes as S
-from repro.core.linear import qlinear
+from repro.core.linear import (_bwd_gemm, _fwd_quant, _pack_qt, _qlinear_fwd,
+                               _unpack_qt, qlinear)
 
 SEED = jnp.array([3, 7], jnp.uint32)
 
@@ -26,6 +28,7 @@ def grads(x, w, scheme, seed=SEED):
 
 
 ALL_SCHEMES = S.names()
+W_IMAGE_SCHEMES = ["quartet2", "four_over_six", "tetrajet_v2", "nvidia"]
 
 
 @pytest.mark.parametrize("scheme", ALL_SCHEMES)
@@ -139,3 +142,54 @@ def test_determinism_given_seed(xw):
     assert np.array_equal(np.asarray(a[1], np.float32), np.asarray(b[1], np.float32))
     c = grads(x, w, "quartet2", jnp.array([5, 6], jnp.uint32))
     assert not np.array_equal(np.asarray(a[1], np.float32), np.asarray(c[1], np.float32))
+
+
+def _f32(a):
+    return np.asarray(jnp.asarray(a, jnp.float32))
+
+
+@pytest.mark.parametrize("scheme", W_IMAGE_SCHEMES)
+def test_backward_reads_the_forward_w_image(xw, scheme):
+    """The forward saves W's NVFP4 image and the backward reads it: dX and dW
+    are bit-equal to a backward that quantizes W again from the raw weight."""
+    x, w = xw
+    sch = S.get(scheme)
+    assert sch.saves_w_image
+    ct = jax.random.normal(jax.random.PRNGKey(2), (2, 64, 384), jnp.bfloat16)
+
+    def loss(x, w):
+        y = qlinear(x, w, SEED, scheme).astype(jnp.float32)
+        return jnp.sum(y * ct.astype(jnp.float32))   # y's cotangent is ct
+
+    dx, dw = jax.grad(loss, (0, 1))(x, w)
+
+    k = x.shape[-1]
+    ef = ct.reshape(-1, ct.shape[-1])
+    qw = _fwd_quant(w, sch.fwd_w)
+    if sch.dx_w_mode == "requant":
+        rdx = _bwd_gemm(ef, Q.dequant(qw).T, sch.bwd, True, True,
+                        use_rht=True, seed=SEED, tag=1)
+    else:  # "reuse": the square-block image as it is, in bf16
+        rdx = _bwd_gemm(ef, Q.dequant(qw, jnp.bfloat16).T, sch.bwd, True, False,
+                        use_rht=False, seed=SEED, tag=1)
+    xq = Q.dequant(_unpack_qt(_pack_qt(_fwd_quant(x.reshape(-1, k), sch.fwd_x))))
+    rdw = _bwd_gemm(ef.T, xq.T, sch.bwd, sch.quant_dw_e, sch.quant_dw_x,
+                    use_rht=sch.rht_dw, seed=SEED, tag=4)
+    assert dx.dtype == x.dtype and dw.dtype == w.dtype
+    np.testing.assert_array_equal(_f32(dx), _f32(rdx.reshape(x.shape).astype(x.dtype)))
+    np.testing.assert_array_equal(_f32(dw), _f32(rdw.astype(w.dtype)))
+
+    _, (_, image, _) = _qlinear_fwd(x, w, SEED, scheme)
+    assert image.shape == w.shape and image.dtype == w.dtype
+    np.testing.assert_array_equal(_f32(image.dequant()), _f32(Q.dequant(qw)))
+
+
+@pytest.mark.parametrize(
+    "scheme", [n for n in ALL_SCHEMES if n not in W_IMAGE_SCHEMES])
+def test_other_schemes_save_the_raw_weight(xw, scheme):
+    """Schemes whose dX GEMM reads W in bf16, or whose backward is bf16,
+    keep W itself as the residual."""
+    x, w = xw
+    assert not S.get(scheme).saves_w_image
+    _, (_, w_res, _) = _qlinear_fwd(x, w, SEED, scheme)
+    assert w_res is w

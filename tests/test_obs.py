@@ -539,6 +539,26 @@ class TestHostSpans:
                             lambda name: contextlib.nullcontext())
         assert lowered().as_text() == low.as_text()
 
+    def test_quartet2_backward_never_quantizes_w_again(self):
+        """The backward reads W's NVFP4 image from the forward's residuals:
+        no op of the transposed (backward) program is a forward quantizer's."""
+        import re
+
+        from repro.core import scopes as SC
+        from repro.train.train_step import make_train_step
+        cfg = _cfg()
+        tok = jax.ShapeDtypeStruct((2, 64), np.int32)
+        init, step = make_train_step(cfg, "quartet2")
+        state = jax.eval_shape(init, jax.eval_shape(
+            lambda: lm.init(cfg, jax.random.PRNGKey(0))))
+        low = jax.jit(step).lower(state, {"tokens": tok, "labels": tok})
+        # compiled, the scan bodies are inlined: every op_name is whole
+        names = re.findall(r'op_name="([^"]*)"', low.compile().as_text())
+        bwd = [n for n in names if "transpose(" in n]
+        assert any(SC.QUANT_BWD in n for n in bwd)
+        assert any(SC.QUANT_FWD in n for n in names)
+        assert not [n for n in bwd if SC.QUANT_FWD in n]
+
 
 # --------------------------------------------------------------------------
 # quantization-health probe
